@@ -22,7 +22,7 @@ def run():
     k = _a((4, 256, 64))
     v = _a((4, 256, 64))
     f = lambda: jax.block_until_ready(
-        ops.flash_attention(q, k, v, bq=128, bk=128))
+        ops.flash_attention(q, k, v, bq=128, bk=128, interpret=True))
     r = lambda: jax.block_until_ready(ref.flash_attention_ref(q, k, v))
     emit("kernel_flash_attn_256", time_us(f), f"ref_us={time_us(r):.0f}")
 
@@ -30,7 +30,8 @@ def run():
     kd = _a((2, 512, 2, 64))
     vd = _a((2, 512, 2, 64))
     valid = jnp.ones((2, 512), bool)
-    f = lambda: jax.block_until_ready(ops.decode_attention(qd, kd, vd, valid))
+    f = lambda: jax.block_until_ready(
+        ops.decode_attention(qd, kd, vd, valid, interpret=True))
     r = lambda: jax.block_until_ready(ref.decode_attention_ref(qd, kd, vd,
                                                                valid))
     emit("kernel_decode_attn_512", time_us(f), f"ref_us={time_us(r):.0f}")
@@ -38,18 +39,18 @@ def run():
     xw = _a((16, 12, 384))
     h0 = _a((16, 128))
     wh = _a((128, 384), s=0.1)
-    f = lambda: jax.block_until_ready(ops.gru_seq(xw, h0, wh))
+    f = lambda: jax.block_until_ready(ops.gru_seq(xw, h0, wh, interpret=True))
     r = lambda: jax.block_until_ready(ref.gru_seq_ref(xw, h0, wh))
     emit("kernel_gru_seq_16x12", time_us(f), f"ref_us={time_us(r):.0f}")
 
     st = _a((20, 150_000))
     w = jnp.ones(20)
-    f = lambda: jax.block_until_ready(ops.fedavg_reduce(st, w))
+    f = lambda: jax.block_until_ready(ops.fedavg_reduce(st, w, interpret=True))
     r = lambda: jax.block_until_ready(ref.fedavg_reduce_ref(st, w))
     emit("kernel_fedavg_150k", time_us(f), f"ref_us={time_us(r):.0f}")
 
     lg = _a((1024, 64))
-    f = lambda: jax.block_until_ready(ops.topk_router(lg, 6))
+    f = lambda: jax.block_until_ready(ops.topk_router(lg, 6, interpret=True))
     r = lambda: jax.block_until_ready(ref.topk_router_ref(lg, 6))
     emit("kernel_topk_router_1k", time_us(f), f"ref_us={time_us(r):.0f}")
 
@@ -58,7 +59,7 @@ def run():
     A = jnp.asarray(-R.uniform(0.5, 2.0, 4), jnp.float32)
     Bm, Cm = _a((2, 128, 8)), _a((2, 128, 8))
     f = lambda: jax.block_until_ready(
-        ops.mamba_chunk_scan(x, dt, A, Bm, Cm, chunk=32))
+        ops.mamba_chunk_scan(x, dt, A, Bm, Cm, chunk=32, interpret=True))
     emit("kernel_mamba_scan_128", time_us(f), "")
 
 

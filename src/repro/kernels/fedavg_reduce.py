@@ -24,7 +24,7 @@ def _fedavg_kernel(x_ref, w_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
 def fedavg_reduce(stacked: jax.Array, weights: jax.Array, *,
-                  bn: int = 16384, interpret: bool = True) -> jax.Array:
+                  bn: int = 16384, interpret: bool = False) -> jax.Array:
     """stacked (C, N) replica matrix; weights (C,) -> (N,) average."""
     C, N = stacked.shape
     bn = min(bn, N)

@@ -69,7 +69,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0, bq: int = 128,
-                    bk: int = 128, interpret: bool = True) -> jax.Array:
+                    bk: int = 128, interpret: bool = False) -> jax.Array:
     """q (BH, T, D); k/v (BH, T, D).  GQA callers fold the group into BH.
     Returns (BH, T, Dv)."""
     BH, T, D = q.shape

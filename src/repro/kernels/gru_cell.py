@@ -39,7 +39,7 @@ def _gru_kernel(xw_ref, h0_ref, wh_ref, o_ref, h_ref, *, T: int, h: int):
 
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))
 def gru_seq(xw: jax.Array, h0: jax.Array, w_h: jax.Array, *, bb: int = 8,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool = False) -> jax.Array:
     """xw (B,T,3h) precomputed input projection; h0 (B,h); w_h (h,3h).
     Returns hidden states (B,T,h)."""
     B, T, h3 = xw.shape

@@ -1,13 +1,16 @@
 """Mamba2 SSD chunk Pallas kernel (zamba2's compute hot spot).
 
-One grid step processes one (batch, head-block) pair and loops over the
-sequence chunks *sequentially inside the kernel*, carrying the (N x P)
-SSD state in VMEM — the TPU-native shape of the recurrence: intra-chunk
-work is two MXU matmuls (C.B^T decay-masked, then score @ u), the
-inter-chunk state update is a rank-N outer-product accumulation.
+Grid: (batch, head-block, chunk).  The chunk axis is sequential
+("arbitrary"): the (N x P) SSD state of every head in the block is
+carried across it in VMEM scratch, while each step's chunk of x / dt /
+B / C is DMA'd in by the pipeline.  Per head the intra-chunk work is two
+MXU matmuls (C.B^T decay-masked, then score @ u) and the inter-chunk
+state update is a rank-Q outer-product accumulation (B^T @ u).
 
-Layout: heads are tiled by ``bh``; B/C are per-group (ngroups=1 for the
-assigned configs) and broadcast across the head tile."""
+Layout: heads are tiled by ``bh``; a block's minor dims are (bh, P), so
+on the TPU ``bh`` is H (or a multiple of 128) and each head's (Q, P)
+column is read with a strided load.  B/C are per-group (ngroups=1 for
+the assigned configs) and shared by every head in the tile."""
 from __future__ import annotations
 
 import functools
@@ -19,53 +22,63 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_final_ref,
-                s_ref, *, nchunks: int, Q: int, bh: int, N: int, P: int):
-    s_ref[...] = jnp.zeros_like(s_ref)
+                s_ref, *, Q: int, bh: int):
+    ci = pl.program_id(2)
 
-    def chunk(ci, _):
-        x = x_ref[0, ci].astype(jnp.float32)          # (Q, bh, P)
-        dt = dt_ref[0, ci].astype(jnp.float32)        # (Q, bh)
-        A = a_ref[...].astype(jnp.float32)            # (bh,)
-        Bm = b_ref[0, ci].astype(jnp.float32)         # (Q, N)
-        Cm = c_ref[0, ci].astype(jnp.float32)         # (Q, N)
+    @pl.when(ci == 0)
+    def _init():
+        s_ref[...] = jnp.zeros_like(s_ref)
 
-        la = dt * A[None, :]                          # (Q, bh) log decay
-        cum = jnp.cumsum(la, axis=0)
-        u = x * dt[..., None]                         # (Q, bh, P)
+    Bm = b_ref[0, 0].astype(jnp.float32)              # (Q, N)
+    Cm = c_ref[0, 0].astype(jnp.float32)              # (Q, N)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    lower = ii >= jj
+    cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)  # (Qi,Qj)
+    la = dt_ref[0, 0].astype(jnp.float32) \
+        * a_ref[...].astype(jnp.float32)              # (Q, bh) log decay
+    # inclusive prefix sum as a lower-triangular mask product (Mosaic
+    # has no cumsum lowering; this is one small MXU matmul)
+    cum = jnp.dot(lower.astype(jnp.float32), la,
+                  preferred_element_type=jnp.float32)  # (Q, bh)
+    for h in range(bh):
+        dt = dt_ref[0, 0, :, h:h + 1].astype(jnp.float32)   # (Q, 1)
+        c_col = cum[:, h:h + 1]                             # (Q, 1)
+        # the same prefix sums as a row: pick the diagonal of the
+        # lane-broadcast column (a VPU reduction, no transpose)
+        c_row = jnp.sum(jnp.where(ii == jj, c_col, 0.0), axis=0,
+                        keepdims=True)                      # (1, Q)
+        u = x_ref[0, 0, :, h, :].astype(jnp.float32) * dt   # (Q, P)
 
-        # intra-chunk: scores (Q,Q) per head tile, decay-masked
-        diff = cum[:, None, :] - cum[None, :, :]      # (Qi, Qj, bh)
-        ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-        jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-        tri = (ii >= jj)[..., None]
-        decay = jnp.where(tri, jnp.exp(diff), 0.0)    # (Q,Q,bh)
-        cb = jnp.dot(Cm, Bm.T,
-                     preferred_element_type=jnp.float32)  # (Qi,Qj)
-        scores = cb[..., None] * decay                # (Q,Q,bh)
-        y_intra = jnp.einsum("ijh,jhp->ihp", scores, u)
+        # intra-chunk: decay-masked scores (Qi, Qj)
+        decay = jnp.where(lower, jnp.exp(c_col - c_row), 0.0)
+        y = jnp.dot(cb * decay, u, preferred_element_type=jnp.float32)
 
         # inter-chunk: contribution of the carried state
-        w_in = jnp.exp(cum)                           # (Q,bh)
-        s_prev = s_ref[...]                           # (bh,N,P)
-        y_inter = jnp.einsum("qn,hnp,qh->qhp", Cm, s_prev, w_in)
-
-        y_ref[0, ci] = (y_intra + y_inter).astype(y_ref.dtype)
+        s_prev = s_ref[h]                                   # (N, P)
+        y += jnp.dot(Cm, s_prev,
+                     preferred_element_type=jnp.float32) * jnp.exp(c_col)
+        y_ref[0, 0, :, h, :] = y.astype(y_ref.dtype)
 
         # state update: S = a_chunk * S_prev + sum_j wlast_j B_j (x) u_j
-        wlast = jnp.exp(cum[-1:, :] - cum)            # (Q,bh)
-        s_loc = jnp.einsum("qn,qhp,qh->hnp", Bm, u, wlast)
-        a_chunk = jnp.exp(cum[-1, :])                 # (bh,)
-        s_ref[...] = a_chunk[:, None, None] * s_prev + s_loc
-        return 0
+        c_last = c_col[Q - 1:Q, :]                          # (1, 1)
+        s_loc = jax.lax.dot_general(
+            Bm, u * jnp.exp(c_last - c_col), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)             # (N, P)
+        # (1,1) -> (1,P) -> (N,P): Mosaic broadcasts one axis at a time
+        a_chunk = jnp.exp(jnp.broadcast_to(c_last, (1, s_prev.shape[1])))
+        s_ref[h] = a_chunk * s_prev + s_loc
 
-    jax.lax.fori_loop(0, nchunks, chunk, 0)
-    s_final_ref[0] = s_ref[...].astype(s_final_ref.dtype)
+    @pl.when(ci == pl.num_programs(2) - 1)
+    def _done():
+        s_final_ref[0] = s_ref[...].astype(s_final_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "bh", "interpret"))
 def mamba_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array,
                      Bm: jax.Array, Cm: jax.Array, *, chunk: int = 64,
-                     bh: int = 0, interpret: bool = True):
+                     bh: int = 0, interpret: bool = False):
     """x (B,L,H,P); dt (B,L,H) post-softplus; A (H,) negative;
     Bm/Cm (B,L,N) (ngroups=1).  Returns (y (B,L,H,P), state (B,H,N,P))."""
     B, L, H, P = x.shape
@@ -78,29 +91,30 @@ def mamba_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array,
     dtr = dt.reshape(B, nchunks, chunk, H)
     Br = Bm.reshape(B, nchunks, chunk, N)
     Cr = Cm.reshape(B, nchunks, chunk, N)
-    kernel = functools.partial(_ssd_kernel, nchunks=nchunks, Q=chunk,
-                               bh=bh, N=N, P=P)
+    kernel = functools.partial(_ssd_kernel, Q=chunk, bh=bh)
     y, s = pl.pallas_call(
         kernel,
-        grid=(B, H // bh),
+        grid=(B, H // bh, nchunks),
         in_specs=[
-            pl.BlockSpec((1, nchunks, chunk, bh, P),
-                         lambda b, h: (b, 0, 0, h, 0)),
-            pl.BlockSpec((1, nchunks, chunk, bh), lambda b, h: (b, 0, 0, h)),
-            pl.BlockSpec((bh,), lambda b, h: (h,)),
-            pl.BlockSpec((1, nchunks, chunk, N), lambda b, h: (b, 0, 0, 0)),
-            pl.BlockSpec((1, nchunks, chunk, N), lambda b, h: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, chunk, bh, P),
+                         lambda b, h, c: (b, c, 0, h, 0)),
+            pl.BlockSpec((1, 1, chunk, bh), lambda b, h, c: (b, c, 0, h)),
+            pl.BlockSpec((1, bh), lambda b, h, c: (0, h)),
+            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, c, 0, 0)),
+            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, c, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, nchunks, chunk, bh, P),
-                         lambda b, h: (b, 0, 0, h, 0)),
-            pl.BlockSpec((1, bh, N, P), lambda b, h: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, chunk, bh, P),
+                         lambda b, h, c: (b, c, 0, h, 0)),
+            pl.BlockSpec((1, bh, N, P), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, nchunks, chunk, H, P), x.dtype),
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bh, N, P), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xr, dtr, A, Br, Cr)
+    )(xr, dtr, A.reshape(1, H), Br, Cr)
     return y.reshape(B, L, H, P), s

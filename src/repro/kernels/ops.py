@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True (this container is CPU-only; interpret
-mode executes the kernel bodies in Python for correctness validation).
-On real TPU hardware pass ``interpret=False`` — same BlockSpecs, same
-code."""
+Every kernel compiles for the TPU by default (``interpret=False``).  On
+the CPU pass ``interpret=True``: interpret mode executes the kernel
+bodies in Python for correctness validation (the tests and
+``benchmarks/kernels_bench.py`` do) — same BlockSpecs, same code."""
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.fedavg_reduce import fedavg_reduce
 from repro.kernels.flash_attention import flash_attention

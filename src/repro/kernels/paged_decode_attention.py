@@ -3,19 +3,21 @@ against a *paged* KV (or MLA latent) cache, gathered through per-sequence
 block tables instead of a contiguous ``(B, C, Hkv, D)`` cache.
 
 Reuses the online-softmax structure of ``kernels/decode_attention.py``
-(grid over key blocks, running max / sum / accumulator scratch), but the
-key block for grid step ``p`` is page ``block_tables[b, p]`` of a global
-``(P, page_size, ...)`` page array — the block table rides in as a
-scalar-prefetch operand so the BlockSpec index map can compute the DMA
-source before the kernel body runs.  Sequences mask by *logical* token
-index: token ``t`` of sequence ``b`` lives at page ``t // page_size``
-slot ``t % page_size`` and is valid iff ``t < lengths[b]`` (and inside
-the sliding window, when one is set).
+(grid (batch, page) over key blocks, running max / sum / accumulator
+scratch), but the key block for grid step ``p`` is page
+``block_tables[b, p]`` of a global ``(P, page_size, ...)`` page array —
+the block table rides in as a scalar-prefetch operand so the BlockSpec
+index map can compute the DMA source before the kernel body runs.
+Sequences mask by *logical* token index: token ``t`` of sequence ``b``
+lives at page ``t // page_size`` slot ``t % page_size`` and is valid iff
+``t < lengths[b]`` (and inside the sliding window, when one is set).
 
 Two variants:
 
-  * :func:`paged_decode_attention` — GQA: the query's G = H/Hkv grouped
-    heads stay together in VMEM so each page is read once per kv head.
+  * :func:`paged_decode_attention` — GQA: a page block holds every kv
+    head (the ``(Hkv, D)`` minor dims tile on the TPU) and each head's
+    G = H/Hkv grouped query heads stay together, so each page is read
+    once.
   * :func:`paged_mla_decode_attention` — DeepSeek MLA with matrix
     absorption: queries arrive already projected into latent space
     (``q_c = q_nope @ w_uk``), scores are taken against the compressed
@@ -42,11 +44,11 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 
 def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, ps: int, scale: float,
+                  acc_ref, m_ref, l_ref, *, ps: int, hkv: int, scale: float,
                   soft_cap: float, window: Optional[int]):
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    np_ = pl.num_programs(2)
+    p = pl.program_id(1)
+    np_ = pl.num_programs(1)
 
     @pl.when(p == 0)
     def _init():
@@ -55,33 +57,36 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     length = len_ref[b]
-    q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)            # (ps, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)            # (ps, Dv)
     tok = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
     ok = tok < length                                 # (1, ps)
     if window is not None:
         ok &= (length - 1 - tok) < window
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if soft_cap:
-        s = jnp.tanh(s / soft_cap) * soft_cap
-    s = jnp.where(ok, s, NEG_INF)                     # (G, ps)
+    # the page block holds every kv head (the (Hkv, D) minor dims tile);
+    # each head reads its (ps, D) column with a strided load
+    for h in range(hkv):
+        q = q_ref[0, h].astype(jnp.float32)           # (G, D)
+        k = k_ref[0, :, h, :].astype(jnp.float32)     # (ps, D)
+        v = v_ref[0, :, h, :].astype(jnp.float32)     # (ps, Dv)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if soft_cap:
+            s = jnp.tanh(s / soft_cap) * soft_cap
+        s = jnp.where(ok, s, NEG_INF)                 # (G, ps)
 
-    m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_cur)
-    pw = jnp.exp(s - m_cur)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(pw, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pw, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_cur
+        m_prev = m_ref[h]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        pw = jnp.exp(s - m_cur)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(pw, axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            pw, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h] = m_cur
 
     @pl.when(p == np_ - 1)
     def _done():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("soft_cap", "window",
@@ -90,7 +95,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, block_tables: jax.Array,
                            lengths: jax.Array, *, soft_cap: float = 0.0,
                            window: Optional[int] = None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q (B,H,D); k/v_pages (P, page_size, Hkv, D); block_tables
     (B, pages_per_seq) i32 page ids (pad rows past a sequence's pages
     with any in-bounds id — they mask out); lengths (B,) i32 valid
@@ -101,9 +106,9 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     G = H // Hkv
     pages_per_seq = block_tables.shape[1]
     qg = q.reshape(B, Hkv, G, D)
-    grid = (B, Hkv, pages_per_seq)
+    grid = (B, pages_per_seq)
     kernel = functools.partial(
-        _paged_kernel, ps=ps, scale=1.0 / math.sqrt(D),
+        _paged_kernel, ps=ps, hkv=Hkv, scale=1.0 / math.sqrt(D),
         soft_cap=soft_cap, window=window)
     out = pl.pallas_call(
         kernel,
@@ -111,19 +116,19 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, G, D),
-                             lambda b, h, p, bt, ln: (b, h, 0, 0)),
-                pl.BlockSpec((1, ps, 1, D),
-                             lambda b, h, p, bt, ln: (bt[b, p], 0, h, 0)),
-                pl.BlockSpec((1, ps, 1, Dv),
-                             lambda b, h, p, bt, ln: (bt[b, p], 0, h, 0)),
+                pl.BlockSpec((1, Hkv, G, D),
+                             lambda b, p, bt, ln: (b, 0, 0, 0)),
+                pl.BlockSpec((1, ps, Hkv, D),
+                             lambda b, p, bt, ln: (bt[b, p], 0, 0, 0)),
+                pl.BlockSpec((1, ps, Hkv, Dv),
+                             lambda b, p, bt, ln: (bt[b, p], 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, Dv),
-                                   lambda b, h, p, bt, ln: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, Hkv, G, Dv),
+                                   lambda b, p, bt, ln: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((G, Dv), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((Hkv, G, Dv), jnp.float32),
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
@@ -184,7 +189,7 @@ def paged_mla_decode_attention(q_c: jax.Array, q_rope: jax.Array,
                                ckv_pages: jax.Array, krope_pages: jax.Array,
                                block_tables: jax.Array, lengths: jax.Array,
                                *, scale: float,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool = False) -> jax.Array:
     """Absorbed-MLA paged decode.  q_c (B,H,R) latent-space queries;
     q_rope (B,H,Dr); ckv/krope_pages (P, page_size, R|Dr); block_tables
     (B, pages_per_seq); lengths (B,).  ``scale`` is the *full* qk scale

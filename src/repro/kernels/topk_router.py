@@ -30,7 +30,7 @@ def _router_kernel(logits_ref, w_ref, i_ref, *, k: int):
 
 @functools.partial(jax.jit, static_argnames=("k", "bt", "interpret"))
 def topk_router(logits: jax.Array, k: int, *, bt: int = 1024,
-                interpret: bool = True):
+                interpret: bool = False):
     """logits (T,E) -> (weights (T,k) f32, idx (T,k) i32)."""
     T, E = logits.shape
     bt = min(bt, T)

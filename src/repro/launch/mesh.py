@@ -6,6 +6,7 @@ any jax initialization)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants (per chip) — roofline denominators
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s
@@ -15,11 +16,18 @@ DCI_BW = 25e9                     # bytes/s effective cross-pod share
 HBM_BYTES = 16 * 1024 ** 3        # 16 GB per chip
 
 
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the model code shards
+    through ``with_sharding_constraint`` and indexes sharded arrays, which
+    ``Explicit`` axes (the default of ``make_mesh``) reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_hfl_mesh(*, n_clusters: int = 4, multi_pod: bool = False):
@@ -27,14 +35,14 @@ def make_hfl_mesh(*, n_clusters: int = 4, multi_pod: bool = False):
     replicas (DESIGN.md §3).  Multi-pod: cluster == pod (2 clusters).
     Single-pod: the 16-wide data axis is split into (cluster, data)."""
     if multi_pod:
-        return jax.make_mesh((2, 16, 16), ("cluster", "data", "model"))
+        return _mesh((2, 16, 16), ("cluster", "data", "model"))
     if 16 % n_clusters != 0:
         raise ValueError("n_clusters must divide 16")
-    return jax.make_mesh((n_clusters, 16 // n_clusters, 16),
-                         ("cluster", "data", "model"))
+    return _mesh((n_clusters, 16 // n_clusters, 16),
+                 ("cluster", "data", "model"))
 
 
 def make_test_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):
     """Small host-device mesh for unit tests (requires
     XLA_FLAGS=--xla_force_host_platform_device_count>=prod(shape))."""
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)

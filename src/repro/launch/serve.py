@@ -3,6 +3,8 @@ workload — the TPU-side realization of the paper's inference path.
 
   PYTHONPATH=src python -m repro.launch.serve --arch xlstm-125m \
       --requests 32 --slots 8
+
+``--no-reduced`` serves the published widths (on the chip).
 """
 from __future__ import annotations
 
@@ -12,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import make_model
 from repro.routing import LatencyModel
 from repro.serving import (ContinuousBatchingScheduler, ServeEngine,
@@ -27,9 +30,16 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--decode-steps", type=int, default=8)
     ap.add_argument("--rate", type=float, default=20.0)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="2-layer CPU-sized variant (--no-reduced: the "
+                         "published config)")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced()
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     api = make_model(cfg)
     params, _ = api.init_params(jax.random.key(0))
     engine = ServeEngine(cfg, params, batch_size=args.slots, max_len=256)

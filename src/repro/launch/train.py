@@ -1,9 +1,9 @@
 """HFL training driver.
 
 Runs the full stack end-to-end: config -> model -> data pipeline ->
-(hierarchical) train step -> aggregation schedule -> checkpoint.  On this
-CPU container use ``--reduced`` (default) to actually execute; the full
-configs are exercised by the dry-run (``repro.launch.dryrun``).
+(hierarchical) train step -> aggregation schedule -> checkpoint.  The
+default ``--reduced`` trains the 2-layer CPU-sized variant;
+``--no-reduced`` trains the published config (on the chip).
 
   PYTHONPATH=src python -m repro.launch.train --arch gemma3-1b \
       --steps 20 --mode hfl --clusters 2 --global-every 2
@@ -21,6 +21,7 @@ from repro.checkpoint import save_pytree
 from repro.configs import get_config
 from repro.data.tokens import TokenStream, TokenStreamConfig
 from repro.fl.collectives import cluster_divergence, stack_for_clusters
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import make_model
 from repro.training.optimizer import AdamW
 from repro.training.train_step import (hfl_global_round, make_hfl_train_step,
@@ -58,9 +59,14 @@ def main() -> None:
                     help="the paper's l: local rounds per global round")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=64)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="2-layer CPU-sized variant (--no-reduced: the "
+                         "published config)")
     ap.add_argument("--checkpoint", default="")
     args = ap.parse_args()
+
+    enable_compile_cache()
 
     full = get_config(args.arch)
     cfg = full.reduced() if args.reduced else full

@@ -50,7 +50,7 @@ class TierSpec:
     arch: str = "gru-traffic"        # config-registry name
     batch_size: int = 1              # engine rows = concurrency cap
     max_len: int = 256
-    reduced: bool = True             # CPU-sized config variant
+    reduced: bool = True             # CPU-sized variant; False: published
     replicas: int = 1                # replicas behind this tier
     # paged cache (transformer families only): batch_size rows share a
     # PagePool instead of each reserving a dense max_len cache

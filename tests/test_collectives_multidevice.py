@@ -12,10 +12,12 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType
     from repro.fl.collectives import (flat_allreduce, global_sync,
                                       hierarchical_allreduce,
                                       stack_for_clusters)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
 
     x = jnp.arange(8.0)
     xs = jax.device_put(x, NamedSharding(mesh, P(("data",))))
@@ -71,12 +73,14 @@ SCRIPT_SM = textwrap.dedent("""
     import numpy as np
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType
     from repro.fl.collectives import (global_sync_shardmap,
                                       make_hfl_local_step_shardmap)
     from repro.fl.compression import (EFState,
                                       compressed_global_sync_shardmap,
                                       init_ef_state)
-    mesh = jax.make_mesh((2, 2, 2), ("cluster", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("cluster", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     sh = NamedSharding(mesh, P("cluster"))
     rng = np.random.default_rng(0)
 

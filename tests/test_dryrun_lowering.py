@@ -11,13 +11,15 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax
+    from jax.sharding import AxisType
     from repro.configs import INPUT_SHAPES, get_config
     from repro.launch import shardings as sh
     from repro.launch.dryrun import build_programs
     from repro.launch.roofline import collective_stats, analyze, model_flops_for
     from repro.launch.analytic import analytic_roofline
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     cfg = get_config("xlstm-125m")
     rules = sh.rules_for(cfg, mesh)
 

@@ -1,5 +1,6 @@
 """Pallas kernel validation: shape/dtype sweeps, assert_allclose against
-the pure-jnp oracles in kernels/ref.py (interpret=True on CPU)."""
+the pure-jnp oracles in kernels/ref.py.  Kernels default to compiling
+for the TPU, so every call here passes ``interpret=True`` (CPU)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -25,7 +26,7 @@ TOL = {jnp.float32: dict(atol=3e-5, rtol=3e-5),
 def test_flash_attention_sweep(T, D, bq, bk, window, dtype):
     q, k, v = (_arr((2, T, D), dtype) for _ in range(3))
     o = ops.flash_attention(q, k, v, causal=True, window=window,
-                            bq=bq, bk=bk)
+                            bq=bq, bk=bk, interpret=True)
     r = ref.flash_attention_ref(q, k, v, causal=True, window=window)
     assert o.dtype == q.dtype
     assert_allclose(np.asarray(o, np.float32), np.asarray(r, np.float32),
@@ -42,7 +43,7 @@ def test_decode_attention_sweep(H, Hkv, C, bk, dtype):
     v = _arr((B, C, Hkv, D), dtype)
     valid = jnp.asarray(R.uniform(size=(B, C)) < 0.8)
     valid = valid.at[:, 0].set(True)     # at least one valid slot
-    o = ops.decode_attention(q, k, v, valid, bk=bk)
+    o = ops.decode_attention(q, k, v, valid, bk=bk, interpret=True)
     r = ref.decode_attention_ref(q, k, v, valid)
     assert_allclose(np.asarray(o, np.float32), np.asarray(r, np.float32),
                     **TOL[dtype])
@@ -54,7 +55,7 @@ def test_gru_seq_sweep(B, T, h, bb):
     xw = _arr((B, T, 3 * h))
     h0 = _arr((B, h))
     wh = _arr((h, 3 * h), scale=0.1)
-    o = ops.gru_seq(xw, h0, wh, bb=bb)
+    o = ops.gru_seq(xw, h0, wh, bb=bb, interpret=True)
     r = ref.gru_seq_ref(xw, h0, wh)
     assert_allclose(np.asarray(o), np.asarray(r), atol=2e-5, rtol=2e-5)
 
@@ -65,7 +66,7 @@ def test_gru_seq_sweep(B, T, h, bb):
 def test_fedavg_reduce_sweep(C, N, bn, dtype):
     x = _arr((C, N), dtype)
     w = jnp.asarray(R.uniform(0.5, 2.0, C), jnp.float32)
-    o = ops.fedavg_reduce(x, w, bn=bn)
+    o = ops.fedavg_reduce(x, w, bn=bn, interpret=True)
     r = ref.fedavg_reduce_ref(x, w)
     assert_allclose(np.asarray(o, np.float32), np.asarray(r, np.float32),
                     **TOL[dtype])
@@ -75,7 +76,7 @@ def test_fedavg_reduce_sweep(C, N, bn, dtype):
                                       (32, 64, 6, 32)])
 def test_topk_router_sweep(T, E, k, bt):
     logits = _arr((T, E))
-    w1, i1 = ops.topk_router(logits, k, bt=bt)
+    w1, i1 = ops.topk_router(logits, k, bt=bt, interpret=True)
     w2, i2 = ref.topk_router_ref(logits, k)
     assert_allclose(np.asarray(w1), np.asarray(w2), atol=1e-6)
     assert (np.asarray(i1) == np.asarray(i2)).all()
@@ -91,7 +92,8 @@ def test_mamba_chunk_scan_sweep(L, H, P, N, chunk):
     A = jnp.asarray(-R.uniform(0.5, 2.0, H), jnp.float32)
     Bm = _arr((B, L, N))
     Cm = _arr((B, L, N))
-    y, s = ops.mamba_chunk_scan(x, dt, A, Bm, Cm, chunk=chunk)
+    y, s = ops.mamba_chunk_scan(x, dt, A, Bm, Cm, chunk=chunk,
+                                interpret=True)
     yr, sr = ref.mamba_chunk_ref(x, dt, A, Bm[:, :, None, :],
                                  Cm[:, :, None, :], chunk)
     assert_allclose(np.asarray(y), np.asarray(yr), atol=5e-4, rtol=5e-4)
@@ -105,8 +107,10 @@ def test_mamba_head_blocking_equivalence():
     dt = jnp.asarray(R.uniform(0.01, 0.2, (B, L, H)), jnp.float32)
     A = jnp.asarray(-R.uniform(0.5, 2.0, H), jnp.float32)
     Bm, Cm = _arr((B, L, N)), _arr((B, L, N))
-    y1, s1 = ops.mamba_chunk_scan(x, dt, A, Bm, Cm, chunk=32, bh=4)
-    y2, s2 = ops.mamba_chunk_scan(x, dt, A, Bm, Cm, chunk=32, bh=2)
+    y1, s1 = ops.mamba_chunk_scan(x, dt, A, Bm, Cm, chunk=32, bh=4,
+                                  interpret=True)
+    y2, s2 = ops.mamba_chunk_scan(x, dt, A, Bm, Cm, chunk=32, bh=2,
+                                  interpret=True)
     assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5, rtol=1e-5)
     assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-5, rtol=1e-5)
 
@@ -136,7 +140,8 @@ def test_paged_decode_attention_sweep(H, Hkv, ps, Pseq, soft_cap, window,
     bt = _block_tables(B, Pseq, num_pages)
     lengths = jnp.asarray(R.integers(1, Pseq * ps + 1, (B,)), jnp.int32)
     o = ops.paged_decode_attention(q, k_pages, v_pages, bt, lengths,
-                                   soft_cap=soft_cap, window=window)
+                                   soft_cap=soft_cap, window=window,
+                                   interpret=True)
     r = ref.paged_decode_attention_ref(q, k_pages, v_pages, bt, lengths,
                                        soft_cap=soft_cap, window=window)
     assert o.dtype == q.dtype
@@ -158,7 +163,8 @@ def test_paged_mla_decode_attention_sweep(H, R_dim, Dr, ps, Pseq, dtype):
     lengths = jnp.asarray(R.integers(1, Pseq * ps + 1, (B,)), jnp.int32)
     scale = 1.0 / np.sqrt(R_dim + Dr)
     o = ops.paged_mla_decode_attention(q_c, q_rope, ckv_pages, krope_pages,
-                                       bt, lengths, scale=scale)
+                                       bt, lengths, scale=scale,
+                                       interpret=True)
     r = ref.paged_mla_decode_attention_ref(q_c, q_rope, ckv_pages,
                                            krope_pages, bt, lengths,
                                            scale=scale)
@@ -178,7 +184,8 @@ def test_paged_decode_attention_matches_dense_gather():
     v_pages = _arr((num_pages, ps, Hkv, D))
     bt = _block_tables(B, Pseq, num_pages)
     lengths = jnp.asarray([Pseq * ps, 11], jnp.int32)
-    o = ops.paged_decode_attention(q, k_pages, v_pages, bt, lengths)
+    o = ops.paged_decode_attention(q, k_pages, v_pages, bt, lengths,
+                                   interpret=True)
     k = k_pages[bt].reshape(B, Pseq * ps, Hkv, D)
     v = v_pages[bt].reshape(B, Pseq * ps, Hkv, D)
     valid = jnp.arange(Pseq * ps)[None, :] < lengths[:, None]
