@@ -5,6 +5,7 @@ window; per-client validation MSE recorded right after the client
 receives the (cluster/global) model."""
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
@@ -20,6 +21,14 @@ from repro.fl.aggregation import cluster_fedavg, fedavg, global_fedavg
 from repro.fl.client import (ClientBatch, eval_clients, stack_clients,
                              train_clients_locally)
 from repro.models import gru
+from repro.telemetry import Telemetry, maybe as _maybe_tel
+
+#: the wall spans of one continual round (``cat="hfl"``), outermost
+#: first: ``hfl.round`` holds the others; ``hfl.data`` holds
+#: ``hfl.data.windows`` and ``hfl.data.upload``
+HFL_SPANS = ("hfl.round", "hfl.data", "hfl.data.windows",
+             "hfl.data.upload", "hfl.train", "hfl.aggregate", "hfl.eval",
+             "hfl.sync")
 
 
 def _even_indices(n: int, k: int) -> np.ndarray:
@@ -69,13 +78,26 @@ class HFLResult:
 
 class ContinualHFL:
     """mode: 'flat' (centralized FedAvg every round),
-             'hier' (cluster aggregation each round, global every l)."""
+             'hier' (cluster aggregation each round, global every l).
+
+    ``telemetry``: if given (and enabled), each round of
+    :meth:`run_rounds` records the wall spans of :data:`HFL_SPANS` —
+    ``hfl.round`` (args ``round`` and ``tier``: ``cluster``, ``global``
+    or ``flat``) around ``hfl.data`` (host windowing of every client,
+    ``hfl.data.windows``, and the four uploads, ``hfl.data.upload``),
+    ``hfl.train`` (dispatch of local training; the device work is
+    asynchronous), ``hfl.aggregate``, ``hfl.eval`` and ``hfl.sync``
+    (the host blocks on the round's results) — and counts
+    ``hfl.rounds.<tier>`` and ``hfl.upload_bytes``.  Telemetry only
+    observes: parameters and results are the same with or without it."""
 
     def __init__(self, cfg: ArchConfig, ds: TrafficDataset,
                  sensors: np.ndarray, topo: ClusterTopology,
-                 run: HFLRunConfig, mode: str = "hier"):
+                 run: HFLRunConfig, mode: str = "hier",
+                 telemetry: Optional[Telemetry] = None):
         assert mode in ("flat", "hier")
         self.cfg, self.ds, self.run, self.mode = cfg, ds, run, mode
+        self._tel = _maybe_tel(telemetry)
         self.sensors = np.asarray(sensors)
         self.topo = topo
         # cluster ids compacted to 0..k-1 for segment ops
@@ -96,7 +118,16 @@ class ContinualHFL:
                               local_epochs=self.run.local_epochs,
                               epoch_s=epoch_s, upload_s=upload_s, **kwargs)
 
-    def _round_data(self, round_idx: int):
+    def _span(self, name: str, **args):
+        """The wall span ``name`` of a round; a null context without
+        telemetry."""
+        if self._tel is None:
+            return contextlib.nullcontext()
+        return self._tel.tracer.wall(name, cat="hfl", **args)
+
+    def _round_windows(self, round_idx: int):
+        """Host arrays of the round: training windows and targets, and
+        validation windows and targets, of every client."""
         r = self.run
         tr, va = continual_split(self.ds, round_idx, r.train_days,
                                  r.val_days, r.shift_steps)
@@ -115,11 +146,17 @@ class ContinualHFL:
             idx = _even_indices(len(X2), r.max_val_windows)
             Xv.append(X2[idx])
             yv.append(y2[idx])
-        train = ClientBatch(X=jnp.asarray(np.stack(Xs)),
-                            y=jnp.asarray(np.stack(ys)))
-        val = ClientBatch(X=jnp.asarray(np.stack(Xv)),
-                          y=jnp.asarray(np.stack(yv)))
-        return train, val
+        return np.stack(Xs), np.stack(ys), np.stack(Xv), np.stack(yv)
+
+    def _round_data(self, round_idx: int):
+        with self._span("hfl.data.windows"):
+            host = self._round_windows(round_idx)
+        with self._span("hfl.data.upload"):
+            X, y, Xv, yv = (jnp.asarray(a) for a in host)
+        if self._tel is not None:
+            self._tel.metrics.counter("hfl.upload_bytes").inc(
+                sum(a.nbytes for a in host))
+        return ClientBatch(X=X, y=y), ClientBatch(X=Xv, y=yv)
 
     def run_rounds(self, rounds: Optional[int] = None,
                    progress: bool = False) -> HFLResult:
@@ -128,29 +165,43 @@ class ContinualHFL:
         mse_hist, loss_hist = [], []
         rng = jax.random.key(r.seed + 1)
         for t in range(rounds):
-            train, val = self._round_data(t)
-            rng, sub = jax.random.split(rng)
-            self.params, losses = train_clients_locally(
-                self.params, train, sub, cfg=self.cfg,
-                epochs=r.local_epochs, batch_size=r.batch_size, lr=r.lr,
-                max_batches=r.max_batches)
             if self.mode == "flat":
-                glob = fedavg(self.params, jnp.asarray(self.weights))
-                self.params = jax.tree.map(
-                    lambda g: jnp.broadcast_to(g, (len(self.sensors),)
-                                               + g.shape), glob)
+                tier = "flat"
+            elif (t + 1) % self.topo.l == 0:
+                tier = "global"
             else:
-                if (t + 1) % self.topo.l == 0:      # global round
-                    self.params = global_fedavg(self.params,
-                                                self.cluster_ids,
-                                                self.weights)
-                else:                                # local round
-                    self.params = cluster_fedavg(self.params,
-                                                 self.cluster_ids,
-                                                 self.weights)
-            val_mse = eval_clients(self.params, val, cfg=self.cfg)
-            mse_hist.append(np.asarray(val_mse))
-            loss_hist.append(np.asarray(losses))
+                tier = "cluster"
+            with self._span("hfl.round", round=t, tier=tier):
+                with self._span("hfl.data"):
+                    train, val = self._round_data(t)
+                with self._span("hfl.train"):
+                    rng, sub = jax.random.split(rng)
+                    self.params, losses = train_clients_locally(
+                        self.params, train, sub, cfg=self.cfg,
+                        epochs=r.local_epochs, batch_size=r.batch_size,
+                        lr=r.lr, max_batches=r.max_batches)
+                with self._span("hfl.aggregate"):
+                    if tier == "flat":
+                        glob = fedavg(self.params,
+                                      jnp.asarray(self.weights))
+                        self.params = jax.tree.map(
+                            lambda g: jnp.broadcast_to(
+                                g, (len(self.sensors),) + g.shape), glob)
+                    elif tier == "global":
+                        self.params = global_fedavg(self.params,
+                                                    self.cluster_ids,
+                                                    self.weights)
+                    else:
+                        self.params = cluster_fedavg(self.params,
+                                                     self.cluster_ids,
+                                                     self.weights)
+                with self._span("hfl.eval"):
+                    val_mse = eval_clients(self.params, val, cfg=self.cfg)
+                with self._span("hfl.sync"):
+                    mse_hist.append(np.asarray(val_mse))
+                    loss_hist.append(np.asarray(losses))
+            if self._tel is not None:
+                self._tel.metrics.counter(f"hfl.rounds.{tier}").inc()
             if progress and (t % 10 == 0 or t == rounds - 1):
                 print(f"  round {t:3d}: mean val MSE "
                       f"{float(np.mean(val_mse)):.5f}")

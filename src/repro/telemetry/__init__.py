@@ -8,7 +8,8 @@ stack shares:
   vectorized request plane).
 - ``tracer`` — :class:`~repro.telemetry.tracer.SpanTracer` (rounds,
   epochs, aggregation windows, deployment swaps, solver phases,
-  serving admit/measure → Chrome/Perfetto trace JSON + JSONL).
+  serving admit/measure, the phases of a continual HFL round →
+  Chrome/Perfetto trace JSON + JSONL).
 - ``audit`` — :class:`~repro.telemetry.audit.DecisionAudit` (every
   orchestration action with trigger, evidence, budget charge, and
   applied/deferred/forced outcome).
@@ -21,6 +22,14 @@ Usage::
     tel.write_trace("trace.json")          # load in ui.perfetto.dev
     tel.audit.write_jsonl("audit.jsonl")
     print(tel.to_prometheus())
+
+Profiler hook: ``Telemetry(annotate=jax.profiler.TraceAnnotation)``
+hands the tracer a factory of context managers that every wall span
+enters around its block, named after the span and given its args.
+Each wall span (solver, serving, HFL round phases) is then also written
+into the profiler's host plane on the profiler's own clock, beside the
+device ops, while the tracer's own record stays the one the rest of the
+repo reads.  The hook is injected by the caller, never imported here.
 
 Zero-overhead contract: instrumented classes resolve
 ``self._tel = maybe(telemetry)`` once at construction — `maybe` returns
@@ -37,7 +46,7 @@ importers stay jax-free.
 from __future__ import annotations
 
 import json
-from typing import Dict, Optional
+from typing import Callable, ContextManager, Dict, Optional
 
 from repro.telemetry.audit import AuditRecord, DecisionAudit, OUTCOMES
 from repro.telemetry.registry import (Counter, Gauge, Histogram,
@@ -56,10 +65,12 @@ __all__ = [
 class Telemetry:
     """Facade bundling a metrics registry, span tracer, and audit log."""
 
-    def __init__(self, enabled: bool = True) -> None:
+    def __init__(self, enabled: bool = True,
+                 annotate: Optional[Callable[..., ContextManager]] = None
+                 ) -> None:
         self.enabled = bool(enabled)
         self.metrics = MetricsRegistry()
-        self.tracer = SpanTracer()
+        self.tracer = SpanTracer(annotate=annotate)
         self.audit = DecisionAudit()
 
     # -- export surface --------------------------------------------------

@@ -9,8 +9,14 @@ Spans live in one of two clock domains:
   subtrees nest correctly), or recorded whole via
   :meth:`SpanTracer.complete` when the duration is known up front.
 - ``wall`` — real ``time.perf_counter`` seconds: solver phases,
-  serving-engine admit/measure.  Recorded with the
-  :meth:`SpanTracer.wall` context manager.
+  serving-engine admit/measure, the phases of a continual HFL round.
+  Recorded with the :meth:`SpanTracer.wall` context manager.  Wall
+  spans nest: each records the name of the wall span open around it
+  (``Span.parent``).  An optional ``annotate`` hook, a factory of
+  context managers called as ``annotate(name, **args)``, is entered
+  around each wall span's block, so a profiler's host annotations
+  (``jax.profiler.TraceAnnotation``) get a copy of every span on the
+  profiler's own clock, beside the device ops.
 
 Exports: :meth:`to_chrome` emits the Chrome trace-event format that
 Perfetto / ``chrome://tracing`` load directly (complete events
@@ -30,7 +36,8 @@ import json
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Dict, Hashable, Iterator, List, Optional
+from typing import (Callable, ContextManager, Dict, Hashable, Iterator,
+                    List, Optional)
 
 _PID = {"sim": 1, "wall": 2}
 
@@ -51,7 +58,8 @@ def wall_clock() -> float:
 @dataclass
 class Span:
     """One closed interval.  ``t0``/``dur`` are seconds in the span's
-    clock domain (sim time or wall time relative to tracer creation)."""
+    clock domain (sim time or wall time relative to tracer creation);
+    ``parent`` names the wall span that was open around it, if any."""
 
     name: str
     t0: float
@@ -60,6 +68,7 @@ class Span:
     tid: int = 0
     domain: str = "sim"
     args: Dict[str, object] = field(default_factory=dict)
+    parent: Optional[str] = None
 
 
 @dataclass
@@ -73,10 +82,13 @@ class Instant:
 
 
 class SpanTracer:
-    def __init__(self) -> None:
+    def __init__(self, annotate: Optional[
+            Callable[..., ContextManager]] = None) -> None:
         self.spans: List[Span] = []
         self.instants: List[Instant] = []
         self._open: Dict[Hashable, Span] = {}
+        self._wall_stack: List[str] = []
+        self._annotate = annotate
         self._wall0 = time.perf_counter()
 
     # -- sim-time spans (explicit event times) -------------------------
@@ -119,13 +131,22 @@ class SpanTracer:
     def wall(self, name: str, cat: str = "", tid: int = 0,
              **args) -> Iterator[Span]:
         """Time a code block on the wall clock; yields the Span so the
-        caller can read ``.dur`` afterwards (solver phase view)."""
+        caller can read ``.dur`` afterwards (solver phase view).  The
+        span's parent is the innermost wall span still open; the
+        ``annotate`` hook, if any, is entered around the block."""
+        stack = self._wall_stack
         sp = Span(name=name, t0=time.perf_counter() - self._wall0,
                   dur=-1.0, cat=cat, tid=tid, domain="wall",
-                  args=dict(args))
+                  args=dict(args), parent=stack[-1] if stack else None)
+        stack.append(name)
         try:
-            yield sp
+            if self._annotate is None:
+                yield sp
+            else:
+                with self._annotate(name, **args):
+                    yield sp
         finally:
+            stack.pop()
             sp.dur = (time.perf_counter() - self._wall0) - sp.t0
             self.spans.append(sp)
 
@@ -148,7 +169,8 @@ class SpanTracer:
     def to_chrome(self) -> List[Dict[str, object]]:
         """Chrome trace-event list (load the written file directly in
         Perfetto or chrome://tracing).  Sim time and wall time become
-        separate processes; still-open spans are omitted."""
+        separate processes; still-open spans are omitted.  A span's
+        parent, if any, is in its ``args`` under ``parent``."""
         events: List[Dict[str, object]] = [
             {"name": "process_name", "ph": "M", "pid": pid, "tid": 0,
              "args": {"name": f"{dom}-time"}}
@@ -158,7 +180,8 @@ class SpanTracer:
                 "name": sp.name, "cat": sp.cat or "span", "ph": "X",
                 "ts": sp.t0 * 1e6, "dur": max(sp.dur, 0.0) * 1e6,
                 "pid": _PID[sp.domain], "tid": sp.tid,
-                "args": dict(sp.args)})
+                "args": (dict(sp.args) if sp.parent is None
+                         else {**sp.args, "parent": sp.parent})})
         for ins in self.instants:
             events.append({
                 "name": ins.name, "cat": ins.cat or "event", "ph": "i",
@@ -179,7 +202,8 @@ class SpanTracer:
                 f.write(json.dumps({
                     "kind": "span", "name": sp.name, "cat": sp.cat,
                     "t0": sp.t0, "dur": sp.dur, "tid": sp.tid,
-                    "domain": sp.domain, "args": sp.args}) + "\n")
+                    "domain": sp.domain, "args": sp.args,
+                    "parent": sp.parent}) + "\n")
             for ins in self.instants:
                 f.write(json.dumps({
                     "kind": "instant", "name": ins.name, "cat": ins.cat,

@@ -241,3 +241,74 @@ def test_telemetry_snapshot_and_facade(tmp_path):
     tel.write_snapshot(str(p))
     assert json.loads(p.read_text())["metrics"]["counters"]["c"] == 1.0
     assert "repro_c 1" in tel.to_prometheus()
+
+
+# -- wall-span nesting and the profiler hook ---------------------------------
+
+def test_wall_spans_record_their_parent(tmp_path):
+    tr = SpanTracer()
+    with tr.wall("round", cat="hfl"):
+        with tr.wall("data", cat="hfl"):
+            with tr.wall("data.windows", cat="hfl"):
+                pass
+        with tr.wall("train", cat="hfl"):
+            pass
+    with tr.wall("after"):
+        pass
+    tr.complete("swap", 1.0, 2.0)
+    parent = {sp.name: sp.parent for sp in tr.spans}
+    assert parent == {"data.windows": "data", "data": "round",
+                      "train": "round", "round": None, "after": None,
+                      "swap": None}
+    # a child closes first and lies inside its parent
+    by = {sp.name: sp for sp in tr.spans}
+    assert [sp.name for sp in tr.spans][:3] == ["data.windows", "data",
+                                                "train"]
+    for child in ("data.windows", "data", "train"):
+        c, p = by[child], by[parent[child]]
+        assert p.t0 <= c.t0 and c.t0 + c.dur <= p.t0 + p.dur
+    xs = {e["name"]: e for e in tr.to_chrome() if e["ph"] == "X"}
+    assert xs["data.windows"]["args"] == {"parent": "data"}
+    assert "parent" not in xs["round"]["args"]
+    path = tmp_path / "spans.jsonl"
+    tr.write_jsonl(str(path))
+    lines = {d["name"]: d for d in map(json.loads,
+                                       path.read_text().splitlines())}
+    assert lines["data"]["parent"] == "round"
+    assert lines["round"]["parent"] is None
+
+
+def test_wall_span_stack_unwinds_on_error():
+    tr = SpanTracer()
+    with pytest.raises(RuntimeError):
+        with tr.wall("outer"):
+            with tr.wall("inner"):
+                raise RuntimeError("boom")
+    with tr.wall("next"):
+        pass
+    assert [(sp.name, sp.parent) for sp in tr.spans] == [
+        ("inner", "outer"), ("outer", None), ("next", None)]
+
+
+def test_annotate_hook_wraps_every_wall_span():
+    from contextlib import contextmanager
+
+    log = []
+
+    @contextmanager
+    def annotate(name, **args):
+        log.append(("enter", name, args))
+        yield
+        log.append(("exit", name))
+
+    tel = Telemetry(annotate=annotate)
+    with tel.tracer.wall("hfl.round", cat="hfl", round=0, tier="cluster"):
+        log.append(("body",))
+        with tel.tracer.wall("hfl.data", cat="hfl"):
+            pass
+    tel.tracer.complete("sim-span", 0.0, 1.0)    # sim spans: no hook
+    assert log == [("enter", "hfl.round", {"round": 0, "tier": "cluster"}),
+                   ("body",), ("enter", "hfl.data", {}),
+                   ("exit", "hfl.data"), ("exit", "hfl.round")]
+    assert [sp.name for sp in tel.tracer.spans] == ["hfl.data", "hfl.round",
+                                                    "sim-span"]
