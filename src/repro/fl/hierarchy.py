@@ -17,7 +17,8 @@ from repro.configs.base import ArchConfig
 from repro.core.topology import ClusterTopology
 from repro.data.traffic import (TrafficDataset, continual_split,
                                 windows_for_sensor)
-from repro.fl.aggregation import cluster_fedavg, fedavg, global_fedavg
+from repro.fl.aggregation import (cluster_fedavg, clusters,
+                                  compiled_programs, fedavg, global_fedavg)
 from repro.fl.client import (ClientBatch, eval_clients, stack_clients,
                              train_clients_locally)
 from repro.models import gru
@@ -88,7 +89,9 @@ class ContinualHFL:
     ``hfl.train`` (dispatch of local training; the device work is
     asynchronous), ``hfl.aggregate``, ``hfl.eval`` and ``hfl.sync``
     (the host blocks on the round's results) — and counts
-    ``hfl.rounds.<tier>`` and ``hfl.upload_bytes``.  Telemetry only
+    ``hfl.rounds.<tier>``, ``hfl.upload_bytes`` and
+    ``hfl.aggregate.compiles`` (aggregation programs compiled in the
+    round: none once each tier has run).  Telemetry only
     observes: parameters and results are the same with or without it."""
 
     def __init__(self, cfg: ArchConfig, ds: TrafficDataset,
@@ -100,15 +103,11 @@ class ContinualHFL:
         self._tel = _maybe_tel(telemetry)
         self.sensors = np.asarray(sensors)
         self.topo = topo
-        # cluster ids compacted to 0..k-1 for segment ops
-        assign = topo.assign[:len(self.sensors)] \
-            if topo.assign.shape[0] >= len(self.sensors) else topo.assign
-        uniq = {int(j): k for k, j in enumerate(np.unique(assign))}
-        self.cluster_ids = np.asarray([uniq[int(j)] for j in assign])
+        # the clients' clusters and (equal) weights, on the device once
+        self.clusters = clusters(topo.assign[:len(self.sensors)])
         rng = jax.random.key(run.seed)
         params0, _ = gru.init_params(rng, cfg.model)
         self.params = stack_clients([params0] * len(self.sensors))
-        self.weights = np.ones(len(self.sensors))
 
     def round_schedule(self, rounds: Optional[int] = None,
                        epoch_s: float = 6.0, upload_s: float = 2.0,
@@ -181,20 +180,22 @@ class ContinualHFL:
                         epochs=r.local_epochs, batch_size=r.batch_size,
                         lr=r.lr, max_batches=r.max_batches)
                 with self._span("hfl.aggregate"):
+                    if self._tel is not None:
+                        compiled = compiled_programs()
                     if tier == "flat":
-                        glob = fedavg(self.params,
-                                      jnp.asarray(self.weights))
-                        self.params = jax.tree.map(
-                            lambda g: jnp.broadcast_to(
-                                g, (len(self.sensors),) + g.shape), glob)
+                        self.params = fedavg(self.params,
+                                             self.clusters.weights,
+                                             broadcast=True)
                     elif tier == "global":
                         self.params = global_fedavg(self.params,
-                                                    self.cluster_ids,
-                                                    self.weights)
+                                                    self.clusters)
                     else:
                         self.params = cluster_fedavg(self.params,
-                                                     self.cluster_ids,
-                                                     self.weights)
+                                                     self.clusters)
+                    if self._tel is not None:
+                        self._tel.metrics.counter(
+                            "hfl.aggregate.compiles").inc(
+                                compiled_programs() - compiled)
                 with self._span("hfl.eval"):
                     val_mse = eval_clients(self.params, val, cfg=self.cfg)
                 with self._span("hfl.sync"):

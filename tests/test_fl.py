@@ -2,12 +2,14 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.fl import (ClientBatch, EFState, cluster_fedavg,
                       compressed_global_sync, dequantize_int8, fedavg,
                       global_fedavg, global_sync, init_ef_state,
                       quantize_int8, stack_clients, stack_for_clusters,
                       sync_bytes)
+from repro.fl.aggregation import clusters, compiled_programs
 
 
 def _stacked(C=6, shape=(4, 3), seed=0):
@@ -46,6 +48,64 @@ def test_global_fedavg_broadcasts_single_model():
     # equal weights: global model = overall mean
     np.testing.assert_allclose(w[0], np.mean(np.asarray(st["w"]), axis=0),
                                rtol=1e-5)
+
+
+def _gru_clients(seed=0):
+    """The GRU's parameter tree for 20 clients in 4 unequal clusters,
+    with unequal weights."""
+    from repro.configs import get_config
+    from repro.models import gru
+    p0, _ = gru.init_params(jax.random.key(0), get_config(
+        "gru-traffic").model)
+    rng = np.random.default_rng(seed)
+    st = jax.tree.map(lambda x: jnp.asarray(
+        rng.normal(size=(20,) + x.shape), jnp.float32), p0)
+    cid = np.repeat([3, 0, 7, 5], [2, 4, 6, 8])      # sparse ids, unequal
+    return st, cid, rng.uniform(0.5, 3.0, 20)
+
+
+@pytest.mark.parametrize("tier", ["cluster", "global", "flat"])
+def test_compiled_aggregation_matches_float64_mean(tier):
+    st, cid, w = _gru_clients()
+    members = [np.nonzero(cid == k)[0] for k in np.unique(cid)]
+    if tier == "cluster":
+        out = cluster_fedavg(st, cid, w)
+    elif tier == "global":
+        out = global_fedavg(st, clusters(cid, w))
+    else:
+        out = fedavg(st, jnp.asarray(w), broadcast=True)
+    for x, y in zip(jax.tree.leaves(st), jax.tree.leaves(out)):
+        x, y = np.asarray(x, np.float64), np.asarray(y)
+        assert y.shape == x.shape and y.dtype == np.float32
+        means = [np.average(x[g], axis=0, weights=w[g]) for g in members]
+        if tier == "cluster":
+            want = np.empty_like(x)
+            for g, m in zip(members, means):
+                want[g] = m
+        elif tier == "global":          # the cluster models, by weight
+            want = np.average(means, axis=0,
+                              weights=[w[g].sum() for g in members])
+        else:
+            want = np.average(x, axis=0, weights=w)
+        want = np.broadcast_to(want, x.shape)
+        assert np.linalg.norm(y - want) <= 1e-6 * np.linalg.norm(want)
+        # the clients that share a model hold the same bits
+        for g in members if tier == "cluster" else [np.arange(20)]:
+            assert (y[g] == y[g[0]]).all()
+
+
+def test_aggregation_compiles_once_per_cluster_count():
+    st, cid, w = _gru_clients()
+    c4 = clusters(cid, w)
+    global_fedavg(st, c4)
+    cluster_fedavg(st, c4)
+    n = compiled_programs()
+    other, _, w2 = _gru_clients(seed=1)
+    global_fedavg(other, c4)                # same shapes, new values
+    cluster_fedavg(other, cid, w2)          # host ids, prepared per call
+    assert compiled_programs() == n
+    cluster_fedavg(st, np.repeat([0, 1, 2, 3, 4], 4))  # five clusters
+    assert compiled_programs() == n + 1
 
 
 def test_global_sync_equals_mean():

@@ -94,6 +94,16 @@ def test_counters_count_rounds_and_uploaded_bytes(setting):
     assert m.value("hfl.upload_bytes") == ROUNDS * per_round
 
 
+def test_aggregation_compiles_only_in_the_first_call(setting):
+    tel = Telemetry()
+    hfl = _hfl(setting, "hier", tel)
+    hfl.run_rounds()                     # a cluster round, a global round
+    first = tel.metrics.value("hfl.aggregate.compiles")
+    assert 0 <= first <= 2
+    hfl.run_rounds()
+    assert tel.metrics.value("hfl.aggregate.compiles") == first
+
+
 def test_telemetry_leaves_results_and_params_bit_identical(setting):
     off = _hfl(setting)
     res_off = off.run_rounds()
