@@ -31,6 +31,37 @@ class MLAConfig:
 
 
 @dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling (DeepSeek-V2, arXiv:2405.04434; Peng et al.,
+    arXiv:2309.00071): rotary frequencies blended between interpolated
+    (``theta * factor``) and original ones across the dims whose number of
+    rotations over ``original_max_position`` lies between ``beta_slow``
+    and ``beta_fast``; the softmax scale is multiplied by
+    ``yarn_mscale(factor, mscale_all_dim) ** 2``.  (YaRN would also scale
+    cos/sin by the ratio of the two mscales, which DeepSeek-V2 sets
+    equal.)"""
+    type: str = "yarn"
+    factor: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+
+
+@dataclass(frozen=True)
+class LoRAConfig:
+    """Low-rank adapters ``x @ A @ B * alpha / rank`` beside frozen
+    projections (Hu et al., arXiv:2106.09685), applied unmerged."""
+    rank: int = 16
+    alpha: float = 32.0
+
+    @property
+    def scale(self) -> float:
+        return self.alpha / self.rank
+
+
+@dataclass(frozen=True)
 class AttentionConfig:
     kind: str = "full"              # full | swa | local_global | mla | none
     num_heads: int = 8
@@ -45,6 +76,10 @@ class AttentionConfig:
     causal: bool = True
     qk_norm: bool = False           # gemma3 QK-norm
     logit_soft_cap: float = 0.0
+    rope_scaling: Optional[RopeScaling] = None
+    # adapters on the four MLA projections (wq, kv down, kv up, wo); the
+    # params of a layer carry them under attn/lora
+    lora: Optional[LoRAConfig] = None
 
 
 # ---------------------------------------------------------------------------
@@ -62,7 +97,6 @@ class MoEConfig:
     aux_loss_coef: float = 0.001
     router_dtype: str = "float32"
     dense_d_ff: int = 0             # FFN size of the leading dense layers
-    capacity_factor: float = 1.25   # dispatch buffer slack (tokens dropped beyond)
 
 
 @dataclass(frozen=True)

@@ -1,10 +1,12 @@
 """deepseek-v2-lite-16b [moe] — MLA kv_lora=512, 2 shared + 64 routed top-6.
 
-27L d_model=2048 16H (GQA kv=16) d_ff=1408 vocab=102400.
-[arXiv:2405.04434]
+27L d_model=2048 16H (GQA kv=16) d_ff=1408 vocab=102400; YaRN rope
+(factor 40 over 4096 original positions, mscale = mscale_all_dim = 0.707).
+[arXiv:2405.04434; huggingface.co/deepseek-ai/DeepSeek-V2-Lite config.json]
 """
 from repro.configs.base import (ArchConfig, AttentionConfig, MLAConfig,
-                                ModelConfig, MoEConfig, RunConfig)
+                                ModelConfig, MoEConfig, RopeScaling,
+                                RunConfig)
 
 CONFIG = ArchConfig(
     model=ModelConfig(
@@ -21,6 +23,10 @@ CONFIG = ArchConfig(
             num_kv_heads=16,
             head_dim=128,
             rope_theta=10_000.0,
+            rope_scaling=RopeScaling(type="yarn", factor=40.0, mscale=0.707,
+                                     mscale_all_dim=0.707,
+                                     original_max_position=4096,
+                                     beta_fast=32.0, beta_slow=1.0),
             mla=MLAConfig(kv_lora_rank=512, q_lora_rank=0,
                           qk_nope_head_dim=128, qk_rope_head_dim=64,
                           v_head_dim=128),

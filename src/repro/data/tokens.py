@@ -47,3 +47,44 @@ class TokenStream:
     def __iter__(self) -> Iterator[Dict[str, np.ndarray]]:
         while True:
             yield self.next_batch()
+
+
+class ClientTokenStreams:
+    """One token stream per FL client, for continual federated training
+    of a language model.  Client ``c``'s ids are drawn i.i.d. from a
+    Zipf(``zipf_a``) law over ``vocab_size`` ranks, each rank mapped to an
+    id by the client's own permutation (numpy ``default_rng([seed, c])``),
+    so clients differ in which ids are common.  The stream is cut into
+    windows: window ``r`` holds ``n`` sequences of ``seq_len + 1`` ids
+    whose ranks are the inverse CDF of ``default_rng([seed, c, r])``'s
+    uniforms, so any round's window is made without the ones before it."""
+
+    def __init__(self, vocab_size: int, seq_len: int, seed: int = 0,
+                 zipf_a: float = 1.2):
+        self.vocab_size, self.seq_len = vocab_size, seq_len
+        self.seed, self.zipf_a = int(seed), zipf_a
+        self.cdf = np.cumsum(_zipf_probs(vocab_size, zipf_a))
+
+    def window(self, client: int, round_idx: int,
+               sequences: int) -> np.ndarray:
+        """Window ``round_idx`` of ``client``'s stream: (sequences,
+        seq_len + 1) int32 ids."""
+        perm = np.random.default_rng([self.seed, client]).permutation(
+            self.vocab_size)
+        u = np.random.default_rng([self.seed, client, round_idx]).random(
+            (sequences, self.seq_len + 1))
+        ranks = np.minimum(np.searchsorted(self.cdf, u, side="right"),
+                           self.vocab_size - 1)
+        return perm[ranks].astype(np.int32)
+
+    def round_tokens(self, clients, round_idx: int, steps: int,
+                     batch: int):
+        """A round's data for every client: training batches (C, steps,
+        batch, seq_len + 1) from the first ``steps * batch`` sequences of
+        its window, and a held-out batch (C, batch, seq_len + 1) from the
+        next ``batch``."""
+        w = np.stack([self.window(int(c), round_idx, (steps + 1) * batch)
+                      for c in clients])
+        C = w.shape[0]
+        train = w[:, :steps * batch].reshape(C, steps, batch, -1)
+        return train, w[:, steps * batch:]

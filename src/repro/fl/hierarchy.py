@@ -2,7 +2,10 @@
 experiments (Fig. 6): 20 clients, 4 clusters, 5 local epochs per round,
 2 local aggregations per global aggregation, sliding continual-learning
 window; per-client validation MSE recorded right after the client
-receives the (cluster/global) model."""
+receives the (cluster/global) model.  The same rounds fine-tune a
+registry language model (latent attention) by LoRA adapters on one
+frozen base, each client on its own token stream; only the adapters are
+aggregated."""
 from __future__ import annotations
 
 import contextlib
@@ -15,21 +18,25 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core.topology import ClusterTopology
+from repro.data.tokens import ClientTokenStreams
 from repro.data.traffic import (TrafficDataset, continual_split,
                                 windows_for_sensor)
 from repro.fl.aggregation import (cluster_fedavg, clusters,
                                   compiled_programs, fedavg, global_fedavg)
-from repro.fl.client import (ClientBatch, eval_clients, stack_clients,
-                             train_clients_locally)
-from repro.models import gru
+from repro.fl.client import (ClientBatch, eval_clients, eval_lm_clients,
+                             stack_clients, train_clients_locally,
+                             train_lm_clients)
+from repro.models import gru, make_model
+from repro.models.lora import init_adapters
 from repro.telemetry import Telemetry, maybe as _maybe_tel
 
 #: the wall spans of one continual round (``cat="hfl"``), outermost
 #: first: ``hfl.round`` holds the others; ``hfl.data`` holds
-#: ``hfl.data.windows`` and ``hfl.data.upload``
+#: ``hfl.data.windows`` (GRU) or ``hfl.data.tokens`` (LM), and
+#: ``hfl.data.upload``
 HFL_SPANS = ("hfl.round", "hfl.data", "hfl.data.windows",
-             "hfl.data.upload", "hfl.train", "hfl.aggregate", "hfl.eval",
-             "hfl.sync")
+             "hfl.data.tokens", "hfl.data.upload", "hfl.train",
+             "hfl.aggregate", "hfl.eval", "hfl.sync")
 
 
 def _even_indices(n: int, k: int) -> np.ndarray:
@@ -61,12 +68,19 @@ class HFLRunConfig:
     max_batches: int = 40            # subsample batches/epoch for speed
     max_val_windows: int = 512
     seed: int = 0
+    local_steps: int = 2             # LM clients: AdamW steps per round,
+                                     # one batch_size batch each
+    clients_per_pass: int = 0        # LM clients taken through the base
+                                     # at once (0: all)
 
 
 @dataclass
 class HFLResult:
-    mse: np.ndarray                  # (rounds, clients) val MSE per round
-    train_loss: np.ndarray           # (rounds, clients)
+    # (rounds, clients) validation loss per round: MSE for the GRU,
+    # held-out next-token cross-entropy for an LM
+    mse: np.ndarray
+    # (rounds, clients); an LM's is (rounds, clients, local_steps)
+    train_loss: np.ndarray
     mode: str
 
     def converged_round(self, tol: float = 1.05) -> int:
@@ -89,15 +103,32 @@ class ContinualHFL:
     ``hfl.train`` (dispatch of local training; the device work is
     asynchronous), ``hfl.aggregate``, ``hfl.eval`` and ``hfl.sync``
     (the host blocks on the round's results) — and counts
-    ``hfl.rounds.<tier>``, ``hfl.upload_bytes`` and
-    ``hfl.aggregate.compiles`` (aggregation programs compiled in the
-    round: none once each tier has run).  Telemetry only
-    observes: parameters and results are the same with or without it."""
+    ``hfl.rounds.<tier>``, ``hfl.upload_bytes`` (the round's data
+    put on the device), ``hfl.model_upload_bytes`` (the client models
+    sent to their aggregators: whole GRUs, or an LM's adapters),
+    ``hfl.train_tokens`` (readings of the GRU's windows, or an LM's
+    tokens, trained on) and ``hfl.aggregate.compiles`` (aggregation
+    programs compiled in the round: none once each tier has run).  An
+    LM's round makes its tokens in ``hfl.data.tokens`` in place of
+    ``hfl.data.windows``.  Telemetry only observes: parameters and
+    results are the same with or without it.
 
-    def __init__(self, cfg: ArchConfig, ds: TrafficDataset,
-                 sensors: np.ndarray, topo: ClusterTopology,
-                 run: HFLRunConfig, mode: str = "hier",
-                 telemetry: Optional[Telemetry] = None):
+    The model is the GRU when ``cfg`` is of the ``rnn`` family: ``ds``
+    is then a :class:`TrafficDataset` and ``sensors`` the clients'
+    sensors.  Otherwise ``cfg`` is a registry LM with latent attention
+    and a LoRA config: ``ds`` is a :class:`ClientTokenStreams` and
+    ``sensors`` each client's stream; ``params`` holds the clients'
+    stacked adapters and ``base`` the one frozen base (made from
+    ``run.seed`` if not given).  An LM's rounds are continual across
+    calls: each round takes window :attr:`window` of every stream and
+    moves it on by one, while a round's tier comes from its place in
+    the call.  The GRU's round ``t`` of a call takes the data split
+    ``t``, so every call starts again at the first."""
+
+    def __init__(self, cfg: ArchConfig, ds, sensors: np.ndarray,
+                 topo: ClusterTopology, run: HFLRunConfig,
+                 mode: str = "hier", telemetry: Optional[Telemetry] = None,
+                 base=None):
         assert mode in ("flat", "hier")
         self.cfg, self.ds, self.run, self.mode = cfg, ds, run, mode
         self._tel = _maybe_tel(telemetry)
@@ -106,7 +137,16 @@ class ContinualHFL:
         # the clients' clusters and (equal) weights, on the device once
         self.clusters = clusters(topo.assign[:len(self.sensors)])
         rng = jax.random.key(run.seed)
-        params0, _ = gru.init_params(rng, cfg.model)
+        self.lm = cfg.model.family != "rnn"
+        if self.lm:
+            if not isinstance(ds, ClientTokenStreams):
+                raise TypeError("an LM's clients read ClientTokenStreams")
+            self.base = (make_model(cfg).init_params(rng)[0]
+                         if base is None else base)
+            params0 = init_adapters(jax.random.fold_in(rng, 1), cfg.model)
+            self.window = 0          # the stream window of the next round
+        else:
+            params0, _ = gru.init_params(rng, cfg.model)
         self.params = stack_clients([params0] * len(self.sensors))
 
     def round_schedule(self, rounds: Optional[int] = None,
@@ -148,14 +188,35 @@ class ContinualHFL:
         return np.stack(Xs), np.stack(ys), np.stack(Xv), np.stack(yv)
 
     def _round_data(self, round_idx: int):
-        with self._span("hfl.data.windows"):
-            host = self._round_windows(round_idx)
+        r = self.run
+        if self.lm:
+            with self._span("hfl.data.tokens"):
+                host = self.ds.round_tokens(self.sensors, round_idx,
+                                            r.local_steps, r.batch_size)
+        else:
+            with self._span("hfl.data.windows"):
+                host = self._round_windows(round_idx)
         with self._span("hfl.data.upload"):
-            X, y, Xv, yv = (jnp.asarray(a) for a in host)
+            arrays = tuple(jnp.asarray(a) for a in host)
         if self._tel is not None:
             self._tel.metrics.counter("hfl.upload_bytes").inc(
                 sum(a.nbytes for a in host))
+        if self.lm:
+            return arrays
+        X, y, Xv, yv = arrays
         return ClientBatch(X=X, y=y), ClientBatch(X=Xv, y=yv)
+
+    def _train_tokens(self, train) -> int:
+        """Readings (GRU windows) or tokens (LM) one round trains on."""
+        r = self.run
+        if self.lm:
+            C, steps, B, S1 = train.shape
+            return C * steps * B * (S1 - 1)
+        C, N, H = train.X.shape[:3]
+        batches = N // r.batch_size
+        if r.max_batches:
+            batches = min(batches, r.max_batches)
+        return C * r.local_epochs * batches * r.batch_size * H
 
     def run_rounds(self, rounds: Optional[int] = None,
                    progress: bool = False) -> HFLResult:
@@ -172,13 +233,24 @@ class ContinualHFL:
                 tier = "cluster"
             with self._span("hfl.round", round=t, tier=tier):
                 with self._span("hfl.data"):
-                    train, val = self._round_data(t)
+                    train, val = self._round_data(
+                        self.window if self.lm else t)
                 with self._span("hfl.train"):
                     rng, sub = jax.random.split(rng)
-                    self.params, losses = train_clients_locally(
-                        self.params, train, sub, cfg=self.cfg,
-                        epochs=r.local_epochs, batch_size=r.batch_size,
-                        lr=r.lr, max_batches=r.max_batches)
+                    if self.lm:
+                        self.params, losses = train_lm_clients(
+                            self.params, self.base, train, cfg=self.cfg,
+                            lr=r.lr, group=r.clients_per_pass)
+                    else:
+                        self.params, losses = train_clients_locally(
+                            self.params, train, sub, cfg=self.cfg,
+                            epochs=r.local_epochs, batch_size=r.batch_size,
+                            lr=r.lr, max_batches=r.max_batches)
+                if self._tel is not None:
+                    self._tel.metrics.counter("hfl.train_tokens").inc(
+                        self._train_tokens(train))
+                    self._tel.metrics.counter("hfl.model_upload_bytes").inc(
+                        sum(x.nbytes for x in jax.tree.leaves(self.params)))
                 with self._span("hfl.aggregate"):
                     if self._tel is not None:
                         compiled = compiled_programs()
@@ -197,10 +269,18 @@ class ContinualHFL:
                             "hfl.aggregate.compiles").inc(
                                 compiled_programs() - compiled)
                 with self._span("hfl.eval"):
-                    val_mse = eval_clients(self.params, val, cfg=self.cfg)
+                    if self.lm:
+                        val_mse = eval_lm_clients(
+                            self.params, self.base, val, cfg=self.cfg,
+                            group=r.clients_per_pass)
+                    else:
+                        val_mse = eval_clients(self.params, val,
+                                               cfg=self.cfg)
                 with self._span("hfl.sync"):
                     mse_hist.append(np.asarray(val_mse))
                     loss_hist.append(np.asarray(losses))
+                if self.lm:
+                    self.window += 1
             if self._tel is not None:
                 self._tel.metrics.counter(f"hfl.rounds.{tier}").inc()
             if progress and (t % 10 == 0 or t == rounds - 1):

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import AttentionConfig
 from repro.models.common import ParamBuilder, shard
-from repro.models.rope import apply_rope
+from repro.models.rope import apply_rope, yarn_mscale
 
 _NEG_INF = -2.0e38  # fp32-safe mask value
 Q_CHUNK_THRESHOLD = 8192
@@ -76,17 +76,20 @@ def _rms_head_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6):
 def _sdpa(q: jax.Array, k: jax.Array, v: jax.Array,
           q_pos: jax.Array, k_pos: jax.Array,
           causal: bool, window, soft_cap: float,
-          k_valid: Optional[jax.Array] = None) -> jax.Array:
+          k_valid: Optional[jax.Array] = None,
+          scale: Optional[float] = None) -> jax.Array:
     """q (B,Tq,Hq,D), k/v (B,Tk,Hkv,D'), positions (Tq,)/(Tk,).
 
     ``window`` may be None, a python int, or a traced scalar (per-layer
-    local:global selection inside a homogeneous layer scan).
+    local:global selection inside a homogeneous layer scan).  ``scale``
+    multiplies the scores (``1/sqrt(D)`` if None).
     Returns (B,Tq,Hq,Dv)."""
     B, Tq, Hq, D = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
     qg = q.reshape(B, Tq, Hkv, G, D)
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32) * scale
     if soft_cap:
         scores = jnp.tanh(scores / soft_cap) * soft_cap
@@ -104,7 +107,7 @@ def _sdpa(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, Tq, Hq, v.shape[-1])
 
 
-def _chunked_sdpa(q, k, v, q_pos, k_pos, causal, window, soft_cap):
+def _chunked_sdpa(q, k, v, q_pos, k_pos, causal, window, soft_cap, scale):
     """Scan over query chunks: peak memory O(Q_CHUNK * Tk)."""
     B, Tq, Hq, D = q.shape
     n = Tq // Q_CHUNK
@@ -113,7 +116,8 @@ def _chunked_sdpa(q, k, v, q_pos, k_pos, causal, window, soft_cap):
 
     def step(_, qc):
         qi, pi = qc
-        o = _sdpa(qi, k, v, pi, k_pos, causal, window, soft_cap)
+        o = _sdpa(qi, k, v, pi, k_pos, causal, window, soft_cap,
+                  scale=scale)
         return None, o
 
     _, outs = jax.lax.scan(step, None, (qs, ps))
@@ -121,11 +125,13 @@ def _chunked_sdpa(q, k, v, q_pos, k_pos, causal, window, soft_cap):
 
 
 def sdpa(q, k, v, q_pos, k_pos, *, causal=True, window=None, soft_cap=0.0,
-         k_valid=None):
+         k_valid=None, scale=None):
     big = q.shape[1] >= Q_CHUNK_THRESHOLD and q.shape[1] % Q_CHUNK == 0
     if big and k_valid is None:
-        return _chunked_sdpa(q, k, v, q_pos, k_pos, causal, window, soft_cap)
-    return _sdpa(q, k, v, q_pos, k_pos, causal, window, soft_cap, k_valid)
+        return _chunked_sdpa(q, k, v, q_pos, k_pos, causal, window, soft_cap,
+                             scale)
+    return _sdpa(q, k, v, q_pos, k_pos, causal, window, soft_cap, k_valid,
+                 scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -435,21 +441,7 @@ def paged_mla_prefill(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
                       inv_freq: Optional[jax.Array],
                       ) -> Tuple[jax.Array, PagedMLACache]:
     """:func:`mla_prefill` math with the latent write paged."""
-    m = a.mla
-    B, S, _ = x.shape
-    H = a.num_heads
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    q_nope, q_rope = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
-    if inv_freq is not None:
-        q_rope = apply_rope(q_rope, positions, inv_freq)
-    c_kv, k_rope = _mla_latents(p, a, x, positions, inv_freq)
-    k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uk"])
-    v = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uv"])
-    k_rope_h = jnp.broadcast_to(k_rope[:, :, None, :],
-                                (B, S, H, m.qk_rope_head_dim))
-    q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k_full = jnp.concatenate([k_nope, k_rope_h], axis=-1)
-    out = sdpa(q_full, k_full, v, positions, positions, causal=True)
+    out, c_kv, k_rope = _mla_attend(p, a, x, positions, inv_freq)
     ps = cache.page_size
     num_pages = cache.ckv_pages.shape[0] - 1
     pages, slots = prefill_page_ids(block_tables, positions, length, ps,
@@ -458,7 +450,7 @@ def paged_mla_prefill(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
         ckv_pages=_page_write(cache.ckv_pages, c_kv, pages, slots),
         krope_pages=_page_write(cache.krope_pages, k_rope, pages, slots),
     )
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), new_cache
+    return _mla_out(p, a, out), new_cache
 
 
 def paged_mla_decode(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
@@ -468,12 +460,9 @@ def paged_mla_decode(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
                      ) -> Tuple[jax.Array, PagedMLACache]:
     """Absorbed MLA decode over the paged latent cache; per-row ``pos``
     (B,), math mirrors :func:`mla_decode`."""
-    m = a.mla
+    _no_adapters(p)
     B = x.shape[0]
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    q_nope, q_rope = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
-    if inv_freq is not None:
-        q_rope = apply_rope(q_rope, pos[:, None], inv_freq)
+    q_nope, q_rope = _mla_q(p, a, x, pos[:, None], inv_freq)
     c_new, kr_new = _mla_latents(p, a, x, pos[:, None], inv_freq)
     ps = cache.page_size
     Pseq = block_tables.shape[1]
@@ -487,10 +476,9 @@ def paged_mla_decode(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
     c_kv = cache.ckv_pages[block_tables].reshape(B, C, -1).astype(x.dtype)
     k_rope = cache.krope_pages[block_tables].reshape(B, C, -1).astype(x.dtype)
     q_c = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"])
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
     s_nope = jnp.einsum("bshr,bcr->bhsc", q_c, c_kv)
     s_rope = jnp.einsum("bshr,bcr->bhsc", q_rope, k_rope)
-    scores = (s_nope + s_rope).astype(jnp.float32) * scale
+    scores = (s_nope + s_rope).astype(jnp.float32) * mla_softmax_scale(a)
     tok = jnp.arange(C, dtype=jnp.int32)[None, :]
     valid = (tok <= pos[:, None])[:, None, None, :]
     scores = jnp.where(valid, scores, _NEG_INF)
@@ -527,37 +515,110 @@ def init_mla_cache(batch: int, capacity: int, a: AttentionConfig,
     )
 
 
+def mla_softmax_scale(a: AttentionConfig) -> float:
+    """``1/sqrt(qk_nope + qk_rope)``, times YaRN's ``mscale_all_dim``
+    temperature squared where the config scales its rope."""
+    m = a.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    rs = a.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
+def lora_delta(x: jax.Array, ad: Dict[str, jax.Array], scale: float,
+               n_in: int = 1) -> jax.Array:
+    """``x @ A @ B * scale`` over the trailing ``n_in`` dims of ``x``,
+    for adapter ``ad = {"a": (*in, r), "b": (r, *out)}``.  Adapters with a
+    leading client axis (``a`` (C, *in, r)) give client ``c``'s delta to
+    the ``c``-th of ``C`` equal blocks of ``x``'s rows."""
+    a, b = ad["a"], ad["b"]
+
+    def one(x, a, b):
+        h = jnp.tensordot(x, a.astype(x.dtype), n_in)
+        return jnp.tensordot(h, b.astype(x.dtype), 1) * jnp.asarray(
+            scale, x.dtype)
+
+    if a.ndim == n_in + 1:
+        return one(x, a, b)
+    C = a.shape[0]
+    out = jax.vmap(one)(x.reshape((C, -1) + x.shape[1:]), a, b)
+    return out.reshape(x.shape[:1] + out.shape[2:])
+
+
+def _no_adapters(p) -> None:
+    if "lora" in p:
+        raise NotImplementedError("absorbed MLA decode takes merged "
+                                  "weights, not adapters")
+
+
+def _mla_q(p, a, x, positions, inv_freq):
+    m = a.mla
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    if "lora" in p:
+        q = q + lora_delta(x, p["lora"]["wq"], a.lora.scale)
+    q_nope, q_rope = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
+    if inv_freq is not None:
+        q_rope = apply_rope(q_rope, positions, inv_freq)
+    return q_nope, q_rope
+
+
 def _mla_latents(p, a, x, positions, inv_freq):
     m = a.mla
     c_kv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"])
-    c_kv = _rms_head_norm(c_kv, p["kv_norm"])
     k_rope = jnp.einsum("bsd,dr->bsr", x, p["w_krope"])
+    if "lora" in p:            # kv down-projection (HF kv_a_proj_with_mqa)
+        delta = lora_delta(x, p["lora"]["kv_a"], a.lora.scale)
+        c_kv = c_kv + delta[..., :m.kv_lora_rank]
+        k_rope = k_rope + delta[..., m.kv_lora_rank:]
+    c_kv = _rms_head_norm(c_kv, p["kv_norm"])
     if inv_freq is not None:
         k_rope = apply_rope(k_rope[:, :, None, :], positions,
                             inv_freq)[:, :, 0, :]
     return c_kv, k_rope
 
 
+def _mla_kv(p, a, c_kv):
+    m = a.mla
+    k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uk"])
+    v = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uv"])
+    if "lora" in p:            # kv up-projection (HF kv_b_proj)
+        delta = lora_delta(c_kv, p["lora"]["kv_b"], a.lora.scale)
+        k_nope = k_nope + delta[..., :m.qk_nope_head_dim]
+        v = v + delta[..., m.qk_nope_head_dim:]
+    return k_nope, v
+
+
+def _mla_out(p, a, out):
+    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    if "lora" in p:
+        y = y + lora_delta(out, p["lora"]["wo"], a.lora.scale, n_in=2)
+    return y
+
+
+def _mla_attend(p, a, x, positions, inv_freq):
+    """Full-sequence causal MLA: the per-head output before ``wo``, and
+    the latents the cache keeps."""
+    m = a.mla
+    B, S, _ = x.shape
+    q_nope, q_rope = _mla_q(p, a, x, positions, inv_freq)
+    c_kv, k_rope = _mla_latents(p, a, x, positions, inv_freq)
+    k_nope, v = _mla_kv(p, a, c_kv)
+    # concat nope+rope per head (rope part broadcast across heads)
+    k_rope_h = jnp.broadcast_to(k_rope[:, :, None, :],
+                                (B, S, a.num_heads, m.qk_rope_head_dim))
+    q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k_full = jnp.concatenate([k_nope, k_rope_h], axis=-1)
+    out = sdpa(q_full, k_full, v, positions, positions, causal=True,
+               scale=mla_softmax_scale(a))
+    return out, c_kv, k_rope
+
+
 def mla_forward(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
                 positions: jax.Array, inv_freq: Optional[jax.Array],
                 ) -> jax.Array:
-    m = a.mla
-    B, S, _ = x.shape
-    H = a.num_heads
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    q_nope, q_rope = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
-    if inv_freq is not None:
-        q_rope = apply_rope(q_rope, positions, inv_freq)
-    c_kv, k_rope = _mla_latents(p, a, x, positions, inv_freq)
-    k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uk"])
-    v = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uv"])
-    # concat nope+rope per head (rope part broadcast across heads)
-    k_rope_h = jnp.broadcast_to(k_rope[:, :, None, :],
-                                (B, S, H, m.qk_rope_head_dim))
-    q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k_full = jnp.concatenate([k_nope, k_rope_h], axis=-1)
-    out = sdpa(q_full, k_full, v, positions, positions, causal=True)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    out, _, _ = _mla_attend(p, a, x, positions, inv_freq)
+    return _mla_out(p, a, out)
 
 
 def mla_prefill(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
@@ -566,21 +627,8 @@ def mla_prefill(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
                 ) -> Tuple[jax.Array, MLACache]:
     """Full-sequence MLA prefill: :func:`mla_forward` math plus a one-shot
     write of the compressed latents for positions ``[0, length)``."""
-    m = a.mla
     B, S, _ = x.shape
-    H = a.num_heads
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    q_nope, q_rope = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
-    if inv_freq is not None:
-        q_rope = apply_rope(q_rope, positions, inv_freq)
-    c_kv, k_rope = _mla_latents(p, a, x, positions, inv_freq)
-    k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uk"])
-    v = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uv"])
-    k_rope_h = jnp.broadcast_to(k_rope[:, :, None, :],
-                                (B, S, H, m.qk_rope_head_dim))
-    q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k_full = jnp.concatenate([k_nope, k_rope_h], axis=-1)
-    out = sdpa(q_full, k_full, v, positions, positions, causal=True)
+    out, c_kv, k_rope = _mla_attend(p, a, x, positions, inv_freq)
     slots = prefill_slots(cache.capacity, positions, length)
     pos_rows = jnp.broadcast_to(positions[None], (B, S))
     new_cache = MLACache(
@@ -589,7 +637,7 @@ def mla_prefill(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
         pos=cache.pos.at[:, slots].set(pos_rows, mode="drop"),
         index=jnp.asarray(length, jnp.int32),
     )
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), new_cache
+    return _mla_out(p, a, out), new_cache
 
 
 def mla_decode(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
@@ -598,15 +646,11 @@ def mla_decode(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
     """Absorbed MLA decode: queries projected into latent space so scores
     are computed against the *compressed* cache directly (beyond-paper
     efficiency; DeepSeek-V2 §"absorption")."""
-    m = a.mla
+    _no_adapters(p)
     B = x.shape[0]
-    H = a.num_heads
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    q_nope, q_rope = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
-    if inv_freq is not None:
-        q_rope = apply_rope(q_rope, pos[None][None].repeat(B, 0), inv_freq)
-    c_new, kr_new = _mla_latents(p, a, x, pos[None][None].repeat(B, 0),
-                                 inv_freq)
+    rows = pos[None][None].repeat(B, 0)
+    q_nope, q_rope = _mla_q(p, a, x, rows, inv_freq)
+    c_new, kr_new = _mla_latents(p, a, x, rows, inv_freq)
     slot = cache.index % cache.capacity
     cache = MLACache(
         c_kv=jax.lax.dynamic_update_slice_in_dim(
@@ -619,12 +663,11 @@ def mla_decode(p: Dict[str, Any], a: AttentionConfig, x: jax.Array,
     )
     # absorb: q_c[b,h,r] = sum_k q_nope[b,h,k] * w_uk[r,h,k]
     q_c = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"])
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
     c_kv = cache.c_kv.astype(x.dtype)      # upcast quantized latents
     s_nope = jnp.einsum("bshr,bcr->bhsc", q_c, c_kv)
     s_rope = jnp.einsum("bshr,bcr->bhsc", q_rope,
                         cache.k_rope.astype(x.dtype))
-    scores = (s_nope + s_rope).astype(jnp.float32) * scale
+    scores = (s_nope + s_rope).astype(jnp.float32) * mla_softmax_scale(a)
     valid = (cache.pos >= 0)[:, None, None, :]
     scores = jnp.where(valid, scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)

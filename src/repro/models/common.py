@@ -97,6 +97,31 @@ def named_sharding_for(mesh, rules, logical: Sequence[Optional[str]],
 # Parameter construction
 # ---------------------------------------------------------------------------
 
+# leading axes that index a stack of independent matrices
+_BATCH_AXES = ("expert", "layers")
+
+
+def fan_in(shape: Sequence[int], axes: Sequence[Optional[str]]) -> int:
+    """The number of inputs a weight contracts per output, read from its
+    logical axes: 2-D ``(in, out)`` weights contract ``shape[0]``; past
+    leading stack axes (``expert``), a weight into the model's width
+    (last axis ``embed``, e.g. ``wo`` (heads, head_dim, embed))
+    contracts every other axis, a per-head map (leading ``heads``, e.g.
+    xLSTM's recurrence (heads, head_dim, head_dim)) its middle ones, and
+    any other (``wq`` (embed, heads, head_dim)) its first."""
+    if len(shape) <= 2:
+        return int(shape[0]) if shape else 1
+    k = 0
+    while axes[k] in _BATCH_AXES:
+        k += 1
+    rest, names = tuple(shape[k:]), tuple(axes[k:])
+    if names[-1] == "embed":
+        return int(np.prod(rest[:-1]))
+    if names[0] == "heads":
+        return int(np.prod(rest[1:-1]))
+    return int(rest[0])
+
+
 class ParamBuilder:
     """Builds a nested param dict and a parallel tree of logical axes.
 
@@ -137,11 +162,7 @@ class ParamBuilder:
             val = (jax.random.normal(self._next_rng(), shape, jnp.float32)
                    * scale).astype(dtype)
         else:  # fan_in
-            fan_in = shape[0] if len(shape) >= 1 else 1
-            if len(shape) >= 2:
-                fan_in = int(np.prod(shape[:-1])) // int(np.prod(shape[:-2])) \
-                    if len(shape) > 2 else shape[0]
-            std = scale / np.sqrt(max(fan_in, 1))
+            std = scale / np.sqrt(max(fan_in(shape, axes), 1))
             val = (jax.random.normal(self._next_rng(), shape, jnp.float32)
                    * std).astype(dtype)
         self._insert(self.params, path, val)

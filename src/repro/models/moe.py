@@ -1,19 +1,18 @@
 """Mixture-of-Experts layer: shared + routed experts, top-k routing.
 
-Dispatch is *sort-based with capacity* (megablocks-style adapted to XLA):
-token->expert assignments are sorted by expert id, each expert processes a
-fixed-capacity contiguous slice via ``lax.dynamic_slice`` inside a scan.
-This avoids the O(tokens x experts x capacity) one-hot dispatch tensors of
-GShard-style einsum dispatch while remaining a static-shape program, and
-maps onto the TPU as E sequential (capacity, d) x (d, d_ff) matmuls whose
-d_ff dimension is sharded over the "model" mesh axis.
+Dispatch is *dropless and sort-based* (megablocks-style): the token->expert
+assignments are sorted by expert id, every expert multiplies its own
+contiguous run of rows in one grouped matmul (``jax.lax.ragged_dot``, the
+TPU's native ragged dot), and the outputs are combined back per token,
+weighted by their router probabilities.  No assignment is dropped, however
+unevenly the router loads the experts; the program stays static-shape
+(``tokens * top_k`` rows in all).
 
-Tokens beyond an expert's capacity are dropped (contribute 0); the
-load-balance auxiliary loss pushes the router toward uniform load.
+The load-balance auxiliary loss (Switch) is returned beside the output;
+the caller decides whether to train on it.
 """
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, Tuple
 
 import jax
@@ -44,11 +43,63 @@ def init_moe(pb: ParamBuilder, path: str, d_model: int, moe: MoEConfig,
         init_mlp(pb, f"{path}/shared", d_model, shared, act)
 
 
-def _capacity(num_tokens: int, moe: MoEConfig) -> int:
-    c = math.ceil(num_tokens * moe.top_k / moe.num_experts
-                  * moe.capacity_factor)
-    c = max(8, -(-c // 8) * 8)  # round up to 8
-    return min(c, num_tokens * moe.top_k)  # never above total assignments
+def _routed_plain(xf, topw, topi, w):
+    """The routed experts' output (t, d): each token's ``topi`` experts
+    weighted by ``topw``, dropless."""
+    t, d = xf.shape
+    K = topi.shape[1]
+    E = w["wo"].shape[0]
+    # sort the t*K assignments by expert; each expert's rows are one
+    # contiguous group of the grouped matmuls
+    flat_e = topi.reshape(t * K)
+    order = jnp.argsort(flat_e)
+    sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+    xs = xf[order // K]                                      # (t*K, d)
+    with jax.named_scope("moe.experts"):
+        if "wi_gate" in w:
+            g = jax.lax.ragged_dot(xs, w["wi_gate"], sizes)
+            u = jax.lax.ragged_dot(xs, w["wi_up"], sizes)
+            h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
+        else:
+            h = jax.nn.gelu(jax.lax.ragged_dot(xs, w["wi"], sizes)
+                            .astype(jnp.float32)).astype(xs.dtype)
+        ys = jax.lax.ragged_dot(h, w["wo"], sizes)           # (t*K, d)
+    # combine: back to (token, slot) order, weighted sum over the slots
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * K, dtype=order.dtype))
+    y = ys.at[inverse].get(unique_indices=True).reshape(t, K, d)
+    return jnp.einsum("tkd,tk->td", y, topw.astype(y.dtype),
+                      preferred_element_type=jnp.float32).astype(xf.dtype)
+
+
+@jax.custom_batching.custom_vmap
+def _routed_rows(xf, topw, topi, w):
+    return _routed_plain(xf, topw, topi, w)
+
+
+@_routed_rows.def_vmap
+def _fold_rows(axis_size, in_batched, xf, topw, topi, w):
+    """Routing is per token, so a vmapped call (the serving engine's
+    per-slot decode) is one call over the rows of every slot:
+    ``ragged_dot`` has no batching rule for it."""
+    if any(jax.tree.leaves(in_batched[3])):
+        raise NotImplementedError("vmap over the expert weights")
+    rows = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+            for a, b in zip((xf, topw, topi), in_batched[:3])]
+    n, t = rows[0].shape[:2]
+    out = _routed_rows(*(a.reshape((n * t,) + a.shape[2:]) for a in rows), w)
+    return out.reshape(n, t, -1), True
+
+
+@jax.custom_jvp
+def _routed(xf, topw, topi, w):
+    return _routed_rows(xf, topw, topi, w)
+
+
+@_routed.defjvp
+def _routed_jvp(primals, tangents):
+    # differentiate the plain program: custom_vmap has no autodiff
+    return jax.jvp(_routed_plain, primals, tangents)
 
 
 def apply_moe(p: Dict[str, Any], moe: MoEConfig, x: jax.Array, act: str,
@@ -59,7 +110,7 @@ def apply_moe(p: Dict[str, Any], moe: MoEConfig, x: jax.Array, act: str,
     E, K = moe.num_experts, moe.top_k
     xf = x.reshape(t, d)
 
-    # --- routing (fp32) ---
+    # --- routing (fp32): softmax scores, top-k kept unnormalised ---
     logits = (xf.astype(jnp.float32) @ p["router"])          # (t, E)
     probs = jax.nn.softmax(logits, axis=-1)
     topw, topi = jax.lax.top_k(probs, K)                     # (t, K)
@@ -69,52 +120,9 @@ def apply_moe(p: Dict[str, Any], moe: MoEConfig, x: jax.Array, act: str,
     P = jnp.mean(probs, axis=0)
     aux = E * jnp.sum(f * P) * moe.aux_loss_coef
 
-    # --- sort token-slot assignments by expert ---
-    flat_e = topi.reshape(t * K)
-    flat_tok = jnp.repeat(jnp.arange(t, dtype=jnp.int32), K)
-    flat_w = topw.reshape(t * K)
-    order = jnp.argsort(flat_e)
-    sort_e = flat_e[order]
-    sort_tok = flat_tok[order]
-    sort_w = flat_w[order]
-    counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
-    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                              jnp.cumsum(counts)[:-1]])
-    C = _capacity(t, moe)
-    # pad so dynamic_slice never clamps its start (clamping would shift
-    # the slice away from the idx bookkeeping below)
-    gathered = jnp.concatenate(
-        [xf[sort_tok], jnp.zeros((C, d), xf.dtype)], axis=0)  # (t*K + C, d)
-    use_gate = "wi_gate" in p
-
-    def one_expert(out_flat, inputs):
-        if use_gate:
-            w1g, w1u, w2, st, cnt = inputs
-        else:
-            w1, w2, st, cnt = inputs
-        xs = jax.lax.dynamic_slice(gathered, (st, jnp.int32(0)), (C, d))
-        idx = st + jnp.arange(C, dtype=jnp.int32)
-        valid = (jnp.arange(C) < cnt) & (idx < t * K)
-        idx = jnp.minimum(idx, t * K - 1)
-        toks = jnp.where(valid, sort_tok[idx], t)            # t = trash row
-        ws = jnp.where(valid, sort_w[idx], 0.0)
-        if use_gate:
-            g = xs @ w1g
-            u = xs @ w1u
-            h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
-        else:
-            h = jax.nn.gelu((xs @ w1).astype(jnp.float32)).astype(xs.dtype)
-        y = (h @ w2) * ws[:, None].astype(xs.dtype)
-        return out_flat.at[toks].add(y), None
-
-    out_flat = jnp.zeros((t + 1, d), x.dtype)                # +1 trash row
-    if use_gate:
-        xs_stack = (p["wi_gate"], p["wi_up"], p["wo"], starts, counts)
-    else:
-        xs_stack = (p["wi"], p["wo"], starts, counts)
-    out_flat, _ = jax.lax.scan(one_expert, out_flat, xs_stack)
-    out = out_flat[:t].reshape(B, S, d)
-    out = shard(out, "batch", "seq", "embed_act")
+    experts = {k: p[k] for k in ("wi_gate", "wi_up", "wi", "wo") if k in p}
+    out = _routed(xf, topw, topi, experts)
+    out = shard(out.reshape(B, S, d), "batch", "seq", "embed_act")
 
     if "shared" in p:
         out = out + apply_mlp(p["shared"], x, act)

@@ -1,5 +1,8 @@
-"""Rotary position embeddings (full, partial, dual-base local/global)."""
+"""Rotary position embeddings (full, partial, dual-base local/global,
+YaRN-scaled)."""
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +20,44 @@ def rope_frequencies(head_dim: int, theta: float,
     rot -= rot % 2
     k = np.arange(rot // 2, dtype=np.float32)
     return (1.0 / (theta ** (2.0 * k / rot))).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``
+    (1 without scaling)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, rs) -> np.ndarray:
+    """YaRN inverse frequencies for a ``head_dim``-wide rotation under
+    :class:`~repro.configs.base.RopeScaling` ``rs``: dims that rotate
+    more than ``beta_fast`` times over the original context keep their
+    frequency, those under ``beta_slow`` are divided by ``factor``, with
+    a linear ramp between (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
+    if rs.type != "yarn":
+        raise ValueError(f"unknown rope scaling {rs.type!r}")
+    if rs.mscale != rs.mscale_all_dim:
+        # their ratio would scale cos and sin; DeepSeek-V2's configs set
+        # both alike, so it is 1 and is not applied
+        raise ValueError("YaRN with mscale != mscale_all_dim")
+    half = head_dim // 2
+    extra = rope_frequencies(head_dim, theta).astype(np.float64)
+    inter = extra / rs.factor
+
+    def dim_of(rotations: float) -> float:
+        return (head_dim * math.log(rs.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(rs.beta_fast)), 0)
+    high = min(math.ceil(dim_of(rs.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp          # 1: original frequency, 0: interpolated
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array,

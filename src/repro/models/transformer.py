@@ -26,7 +26,7 @@ from repro.models.layers import (apply_mlp, apply_norm, embed_tokens,
                                  init_embedding, init_mlp, init_norm,
                                  logits_from_hidden)
 from repro.models.moe import apply_moe, init_moe
-from repro.models.rope import rope_frequencies
+from repro.models.rope import rope_frequencies, yarn_frequencies
 
 FULL_WINDOW = 1 << 30
 
@@ -68,6 +68,8 @@ def stacked_rope(cfg: ModelConfig, layers=None) -> jax.Array:
         th = layer_theta(cfg, i)
         if th == 0.0:
             rows.append(np.zeros((0,), np.float32))
+        elif a.rope_scaling is not None:
+            rows.append(yarn_frequencies(hd, th, a.rope_scaling))
         else:
             rows.append(np.asarray(
                 rope_frequencies(hd, th, a.rope_fraction)))
@@ -168,11 +170,12 @@ def forward(params, cfg: ModelConfig, tokens: jax.Array,
     moe_layer = cfg.moe is not None
     n_lead = cfg.moe.first_dense_layers if cfg.moe else 0
     aux_total = jnp.zeros((), jnp.float32)
+    lead_fwd = _layer_fwd if remat == "none" else jax.checkpoint(
+        _layer_fwd, static_argnums=(0, 6))
     for i in range(n_lead):
         p = params["lead"][str(i)]
-        x, aux = _layer_fwd(cfg, p, x, positions,
-                            stacked_rope(cfg, [i])[0],
-                            jnp.int32(layer_window(cfg, i)), False)
+        x, aux = lead_fwd(cfg, p, x, positions, stacked_rope(cfg, [i])[0],
+                          jnp.int32(layer_window(cfg, i)), False)
         aux_total += aux
 
     inv_freqs = stacked_rope(cfg, range(n_lead, cfg.num_layers))

@@ -21,12 +21,6 @@ B = 2
 def _fp32(cfg):
     model = dataclasses.replace(cfg.model, dtype="float32",
                                 param_dtype="float32")
-    if model.moe is not None:
-        # batch vs stepwise dispatch must see identical (no-drop) capacity:
-        # capacity drops are a function of the token-batch size, which is
-        # the one intentional semantic difference between the two paths.
-        model = dataclasses.replace(model, moe=dataclasses.replace(
-            model.moe, capacity_factor=float(model.moe.num_experts)))
     return dataclasses.replace(cfg, model=model)
 
 

@@ -22,6 +22,10 @@ CHILDREN = {"hfl.data": "hfl.round", "hfl.data.windows": "hfl.data",
             "hfl.sync": "hfl.round"}
 
 
+# a GRU round windows sensor readings; an LM's makes tokens instead
+GRU_SPANS = tuple(n for n in HFL_SPANS if n != "hfl.data.tokens")
+
+
 @pytest.fixture(scope="module")
 def setting():
     ds = generate(num_days=3, n_sensors=12, seed=0)
@@ -52,7 +56,7 @@ def test_each_round_records_the_span_tree(setting, mode):
     tel = Telemetry()
     _hfl(setting, mode, tel).run_rounds()
     spans = tel.tracer.spans
-    assert {sp.name for sp in spans} == set(HFL_SPANS)
+    assert {sp.name for sp in spans} == set(GRU_SPANS)
     assert all(sp.cat == "hfl" and sp.domain == "wall" and sp.dur >= 0
                for sp in spans)
     rounds = [sp for sp in spans if sp.name == "hfl.round"]
@@ -92,6 +96,22 @@ def test_counters_count_rounds_and_uploaded_bytes(setting):
     per_round = len(sensors) * 4 * (n_train + run.max_val_windows) \
         * (run.history + 1)
     assert m.value("hfl.upload_bytes") == ROUNDS * per_round
+
+
+def test_counters_count_model_uploads_and_trained_readings(setting):
+    """A GRU round sends every client's whole model to its aggregator
+    and trains on every reading of its windows, each local epoch."""
+    _, _, sensors, _, run = setting
+    tel = Telemetry()
+    hfl = _hfl(setting, "hier", tel)
+    hfl.run_rounds()
+    m = tel.metrics
+    model = sum(np.asarray(x).nbytes for x in jax.tree.leaves(hfl.params))
+    assert m.value("hfl.model_upload_bytes") == ROUNDS * model
+    batches = min((run.train_days * 288 - run.history) // run.batch_size,
+                  run.max_batches)
+    assert m.value("hfl.train_tokens") == ROUNDS * len(sensors) \
+        * run.local_epochs * batches * run.batch_size * run.history
 
 
 def test_aggregation_compiles_only_in_the_first_call(setting):
@@ -137,7 +157,7 @@ def test_profiler_trace_holds_every_forwarded_span(setting, tmp_path):
                     ("run_rounds",) + HFL_SPANS)
     got = sorted(name for name, _, _ in tr.host if name.startswith("hfl."))
     want = sorted(sp.name for sp in tel.tracer.spans[n0:])
-    assert got == want and len(got) == ROUNDS * len(HFL_SPANS)
+    assert got == want and len(got) == ROUNDS * len(GRU_SPANS)
     # on the profiler's clock each child lies inside an annotation of its
     # parent, and every span inside the caller's own
     outer = [(s, e) for n, s, e in tr.host if n == "run_rounds"]

@@ -22,9 +22,6 @@ from repro.serving.workload import RequestEvent
 def _fp32(cfg):
     model = dataclasses.replace(cfg.model, dtype="float32",
                                 param_dtype="float32")
-    if model.moe is not None:
-        model = dataclasses.replace(model, moe=dataclasses.replace(
-            model.moe, capacity_factor=float(model.moe.num_experts)))
     return dataclasses.replace(cfg, model=model)
 
 
