@@ -9,6 +9,7 @@ aggregated."""
 from __future__ import annotations
 
 import contextlib
+import functools
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
@@ -44,6 +45,36 @@ def _even_indices(n: int, k: int) -> np.ndarray:
     if n <= k:
         return np.arange(n)
     return np.linspace(0, n - 1, k).astype(int)
+
+
+@functools.partial(jax.jit, static_argnames=("train_len", "val_len",
+                                             "history", "max_val"))
+def device_windows(series, starts, *, train_len: int, val_len: int,
+                   history: int, max_val: int):
+    """One round's windows, made on the device from the clients' staged
+    normalized series ``(T, C)``: ``X (C, N, history, 1)``, ``y (C, N,
+    1)`` over the ``train_len`` readings from ``starts[0]``, and ``Xv``,
+    ``yv`` over the ``max_val`` evenly spaced windows of the
+    ``val_len`` readings from ``starts[1]`` -- the values
+    ``windows_for_sensor`` gives, sensor by sensor.  The starts are
+    traced, so one program serves every round."""
+
+    def windows(start, length, pick):
+        z = jax.lax.dynamic_slice_in_dim(series, start, length)
+        n = length - history
+        # (n, C, history + 1): window i holds readings i .. i + history
+        w = jnp.stack([z[h:h + n] for h in range(history + 1)], axis=-1)
+        w = jnp.swapaxes(w[pick], 0, 1)
+        return w[..., :history, None], w[..., history:]
+
+    X, y = windows(starts[0], train_len, slice(None))
+    # subsample the val week EVENLY: max_val_windows contiguous windows
+    # cover only ~max_val_windows*5min, so a truncated prefix slides
+    # through the daily cycle as rounds shift and the metric tracks
+    # time-of-day, not learning
+    Xv, yv = windows(starts[1], val_len,
+                     _even_indices(val_len - history, max_val))
+    return X, y, Xv, yv
 
 
 # ---------------------------------------------------------------------------
@@ -98,19 +129,23 @@ class ContinualHFL:
     ``telemetry``: if given (and enabled), each round of
     :meth:`run_rounds` records the wall spans of :data:`HFL_SPANS` —
     ``hfl.round`` (args ``round`` and ``tier``: ``cluster``, ``global``
-    or ``flat``) around ``hfl.data`` (host windowing of every client,
-    ``hfl.data.windows``, and the four uploads, ``hfl.data.upload``),
+    or ``flat``) around ``hfl.data`` (the clients' series staged on the
+    device in the first GRU round, ``hfl.data.upload``, then the split
+    and the dispatch of :func:`device_windows`, ``hfl.data.windows``),
     ``hfl.train`` (dispatch of local training; the device work is
     asynchronous), ``hfl.aggregate``, ``hfl.eval`` and ``hfl.sync``
     (the host blocks on the round's results) — and counts
-    ``hfl.rounds.<tier>``, ``hfl.upload_bytes`` (the round's data
-    put on the device), ``hfl.model_upload_bytes`` (the client models
+    ``hfl.rounds.<tier>``, ``hfl.upload_bytes`` (the data the host puts
+    on the device: the staged series once, then each round's two window
+    starts), ``hfl.window_bytes`` (the GRU windows a round makes on the
+    device), ``hfl.model_upload_bytes`` (the client models
     sent to their aggregators: whole GRUs, or an LM's adapters),
     ``hfl.train_tokens`` (readings of the GRU's windows, or an LM's
     tokens, trained on) and ``hfl.aggregate.compiles`` (aggregation
     programs compiled in the round: none once each tier has run).  An
-    LM's round makes its tokens in ``hfl.data.tokens`` in place of
-    ``hfl.data.windows``.  Telemetry only observes: parameters and
+    LM's round makes its tokens on the host in ``hfl.data.tokens`` and
+    uploads them in ``hfl.data.upload``, in place of the GRU's staging
+    and ``hfl.data.windows``.  Telemetry only observes: parameters and
     results are the same with or without it.
 
     The model is the GRU when ``cfg`` is of the ``rnn`` family: ``ds``
@@ -147,6 +182,8 @@ class ContinualHFL:
             self.window = 0          # the stream window of the next round
         else:
             params0, _ = gru.init_params(rng, cfg.model)
+            self.series = None       # the clients' normalized series,
+                                     # staged in the first round
         self.params = stack_clients([params0] * len(self.sensors))
 
     def round_schedule(self, rounds: Optional[int] = None,
@@ -164,28 +201,9 @@ class ContinualHFL:
             return contextlib.nullcontext()
         return self._tel.tracer.wall(name, cat="hfl", **args)
 
-    def _round_windows(self, round_idx: int):
-        """Host arrays of the round: training windows and targets, and
-        validation windows and targets, of every client."""
-        r = self.run
-        tr, va = continual_split(self.ds, round_idx, r.train_days,
-                                 r.val_days, r.shift_steps)
-        Xs, ys, Xv, yv = [], [], [], []
-        for s in self.sensors:
-            X, y = windows_for_sensor(self.ds, int(s), tr.start, tr.stop,
-                                      r.history)
-            Xs.append(X)
-            ys.append(y)
-            X2, y2 = windows_for_sensor(self.ds, int(s), va.start, va.stop,
-                                        r.history)
-            # subsample the val week EVENLY: max_val_windows contiguous
-            # windows cover only ~max_val_windows*5min, so a truncated
-            # prefix slides through the daily cycle as rounds shift and
-            # the metric tracks time-of-day, not learning
-            idx = _even_indices(len(X2), r.max_val_windows)
-            Xv.append(X2[idx])
-            yv.append(y2[idx])
-        return np.stack(Xs), np.stack(ys), np.stack(Xv), np.stack(yv)
+    def _count(self, name: str, n: int):
+        if self._tel is not None:
+            self._tel.metrics.counter(name).inc(n)
 
     def _round_data(self, round_idx: int):
         r = self.run
@@ -193,17 +211,26 @@ class ContinualHFL:
             with self._span("hfl.data.tokens"):
                 host = self.ds.round_tokens(self.sensors, round_idx,
                                             r.local_steps, r.batch_size)
-        else:
-            with self._span("hfl.data.windows"):
-                host = self._round_windows(round_idx)
-        with self._span("hfl.data.upload"):
-            arrays = tuple(jnp.asarray(a) for a in host)
-        if self._tel is not None:
-            self._tel.metrics.counter("hfl.upload_bytes").inc(
-                sum(a.nbytes for a in host))
-        if self.lm:
+            with self._span("hfl.data.upload"):
+                arrays = tuple(jnp.asarray(a) for a in host)
+            self._count("hfl.upload_bytes", sum(a.nbytes for a in host))
             return arrays
-        X, y, Xv, yv = arrays
+        with self._span("hfl.data.upload"):
+            if self.series is None:
+                self.series = jnp.asarray(self.ds.normalized()[
+                    :, self.sensors].astype(np.float32))
+                self._count("hfl.upload_bytes", self.series.nbytes)
+        with self._span("hfl.data.windows"):
+            tr, va = continual_split(self.ds, round_idx, r.train_days,
+                                     r.val_days, r.shift_steps)
+            starts = np.array([tr.start, va.start], np.int32)
+            X, y, Xv, yv = device_windows(
+                self.series, starts, train_len=tr.stop - tr.start,
+                val_len=va.stop - va.start, history=r.history,
+                max_val=r.max_val_windows)
+        self._count("hfl.upload_bytes", starts.nbytes)
+        self._count("hfl.window_bytes",
+                    sum(a.nbytes for a in (X, y, Xv, yv)))
         return ClientBatch(X=X, y=y), ClientBatch(X=Xv, y=yv)
 
     def _train_tokens(self, train) -> int:
@@ -246,11 +273,9 @@ class ContinualHFL:
                             self.params, train, sub, cfg=self.cfg,
                             epochs=r.local_epochs, batch_size=r.batch_size,
                             lr=r.lr, max_batches=r.max_batches)
-                if self._tel is not None:
-                    self._tel.metrics.counter("hfl.train_tokens").inc(
-                        self._train_tokens(train))
-                    self._tel.metrics.counter("hfl.model_upload_bytes").inc(
-                        sum(x.nbytes for x in jax.tree.leaves(self.params)))
+                self._count("hfl.train_tokens", self._train_tokens(train))
+                self._count("hfl.model_upload_bytes",
+                            sum(x.nbytes for x in jax.tree.leaves(self.params)))
                 with self._span("hfl.aggregate"):
                     if self._tel is not None:
                         compiled = compiled_programs()
@@ -281,8 +306,7 @@ class ContinualHFL:
                     loss_hist.append(np.asarray(losses))
                 if self.lm:
                     self.window += 1
-            if self._tel is not None:
-                self._tel.metrics.counter(f"hfl.rounds.{tier}").inc()
+            self._count(f"hfl.rounds.{tier}", 1)
             if progress and (t % 10 == 0 or t == rounds - 1):
                 print(f"  round {t:3d}: mean val MSE "
                       f"{float(np.mean(val_mse)):.5f}")

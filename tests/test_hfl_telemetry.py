@@ -83,19 +83,24 @@ def test_each_round_records_the_span_tree(setting, mode):
 
 
 def test_counters_count_rounds_and_uploaded_bytes(setting):
-    _, _, sensors, _, run = setting
+    _, ds, sensors, _, run = setting
     tel = Telemetry()
     _hfl(setting, "hier", tel).run_rounds()
     m = tel.metrics
     assert m.value("hfl.rounds.cluster") == 1.0
     assert m.value("hfl.rounds.global") == 1.0
     assert m.value("hfl.rounds.flat", default=0.0) == 0.0
-    # float32 windows of `history` readings and their targets, for the
-    # training windows and the validation windows of every client
+    # the host stages every client's float32 series once, then sends
+    # each round's two int32 window starts
+    staged = ds.num_steps * len(sensors) * 4
+    assert m.value("hfl.upload_bytes") == staged + ROUNDS * 2 * 4
+    # each round the device makes float32 windows of `history` readings
+    # and their targets, for the training windows and the validation
+    # windows of every client
     n_train = run.train_days * 288 - run.history
     per_round = len(sensors) * 4 * (n_train + run.max_val_windows) \
         * (run.history + 1)
-    assert m.value("hfl.upload_bytes") == ROUNDS * per_round
+    assert m.value("hfl.window_bytes") == ROUNDS * per_round
 
 
 def test_counters_count_model_uploads_and_trained_readings(setting):
